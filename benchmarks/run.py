@@ -43,6 +43,7 @@ from benchmarks import (
     table8_exploratory,
 )
 from benchmarks.common import OUT_DIR
+from repro.launch.compile_cache import configure_compile_cache
 
 MODULES = [
     ("fig07", fig07_orderkey_selectivity),
@@ -105,6 +106,7 @@ def main():
         help="trace benchmarks that accept a tracer; arms coverage gates",
     )
     args = ap.parse_args()
+    configure_compile_cache()
     quick = not args.full
     failures = 0
     for name, mod in MODULES:

@@ -38,11 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.4.35 re-export
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.dist.sharding import dp_axes
 
 
@@ -82,11 +77,11 @@ def grad_allreduce_compressed(grads, errors, mesh):
         return reduced.astype(g.dtype), compensated - dq
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def run(gs, es):
         pairs = jax.tree.map(per_rank, gs, es)

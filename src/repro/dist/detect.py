@@ -45,11 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.4.35 re-export
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.constraints import DC, FD, equality_key_attrs, flip_op
 from repro.core.detect import DCDetectResult, FDDetectResult, _T1_REDUCE
 from repro.core.relation import Relation
@@ -191,14 +186,14 @@ def _per_shard_fn(fn, mesh, n_shards: int):
     extent = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
     if extent > 1 and n_shards % extent == 0:
         spec = P(axes if len(axes) > 1 else axes[0])
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             batched,
             mesh=mesh,
             # one positional argument; the bare spec is a pytree prefix
             # applying to every leaf of the args pytree
             in_specs=(spec,),
             out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sharded)
     return jax.jit(batched)
@@ -370,13 +365,14 @@ def detect_dc_sharded_info(
         _unroute(s, res.src, res.valid, cap, _identity(dtypes[n], red))
         for s, n, red in zip(t2s, l_names, t2_red)
     )
-    per_tile = kops._tile_bytes(
-        kops.distinct_columns(args[0], args[1])[0], args[0], args[1], block
+    per_shard_bytes = kops._scan_bytes(
+        kops.distinct_columns(args[0], args[1])[0], len(ops), block, hi, hi,
+        nb_local,
     )
     det = DCDetectResult(
         t1_count, t2_count, t1_stat, t2_stat,
         tiles_launched=tiles_launched, tiles_total=tiles_total,
-        bytes_moved=tiles_launched * per_tile,
+        bytes_moved=n_shards * per_shard_bytes,
     )
     info = _info(res, n_shards, factor, retries, cap, strip_rows=strip_rows)
     info.tiles_launched = tiles_launched

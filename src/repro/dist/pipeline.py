@@ -18,11 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.4.35 re-export
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def pipeline_apply(
     fn: Callable,
@@ -47,11 +42,11 @@ def pipeline_apply(
     perm = [(i, (i + 1) % stages) for i in range(stages)]
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(p_local, xg):
         p = jax.tree.map(lambda a: a[0], p_local)  # drop the local stage dim

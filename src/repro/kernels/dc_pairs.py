@@ -1,9 +1,10 @@
-"""Pallas TPU kernels: blocked theta-join scans for DC violation detection.
+"""Pallas TPU kernel: the fused blocked theta-join scan for DC violation
+detection.
 
 The paper's DC error detection partitions the cartesian-product matrix into
 ``p`` partitions and prunes partitions whose boundary ranges cannot produce a
-violation (§4.2, Fig. 3/4).  On TPU this becomes a grid of (BM, BN) VMEM
-tiles over the comparison matrix:
+violation (§4.2, Fig. 3/4).  On TPU this becomes a grid of (block, block)
+VMEM tiles over the comparison matrix:
 
 * **block-sparse worklist grid** (DESIGN.md §15): the launch is a 1-D grid
   over a host-built worklist of *active* tile pairs — the cross product of
@@ -14,27 +15,40 @@ tiles over the comparison matrix:
   move bytes.  A contiguous ``(lo, hi)`` range and the dense grid are just
   worklists that happen to be ``arange``s — one code path for all of them;
 * per-tile **bound pruning**: per-block min/max of each atom column are
-  precomputed (scope-masked) and indexed by the same prefetched ids; a tile
-  whose bounds make some atom unsatisfiable everywhere skips its body with
-  ``@pl.when`` — the paper's partition pruning, at tile granularity, on top
-  of the worklist sparsity;
-* the 8x128-lane VPU evaluates the atom predicates for all BM*BN pairs of
-  the tile at once (the Spark version loops over JVM tuples);
+  precomputed (scope-masked), held whole in SMEM and indexed by the same
+  prefetched ids; a tile whose bounds make some atom unsatisfiable
+  everywhere skips its body with ``@pl.when`` — the paper's partition
+  pruning, at tile granularity, on top of the worklist sparsity;
+* the VPU evaluates the atom predicates for all block x block pairs of the
+  tile at once (the Spark version loops over JVM tuples);
 * outputs are row-indexed (violation count + per-atom extremal partner
   value, which is the bound of the candidate *range* fix, Example 4) and
   accumulate across the worklist's column-innermost order — each output
   block is revisited consecutively, as the TPU grid requires.
 
-Two entry points share the machinery: ``dc_role_scan_pallas`` is the
-single-role scan, and ``dc_pair_scan_pallas`` fuses BOTH tuple roles (t1
-with the atoms as written, t2 with them flipped — see core/detect.py) into
-one launch over one worklist, loading each distinct atom column once per
-tile instead of twice (DESIGN.md §15's fusion contract).
+Both tuple roles (t1 with the atoms as written, t2 with them flipped — see
+core/detect.py) run in one launch over one worklist, loading each distinct
+atom column once per tile instead of twice (DESIGN.md §15's fusion
+contract).
+
+Layout (DESIGN.md §7).  Mosaic compiles only lane-dense, natively tiled
+blocks, so every column and scope travels as ``(nb, 1, block)``: a tile is
+one ``(1, block)`` row of lanes, DMA'd in its own (possibly narrow,
+encoded) dtype and widened to 32 bits in VMEM, which is exact.  A pairwise
+predicate needs the row values down the sublanes, so the row tile is
+transposed once per worklist row — when its column sweep starts — into a
+lane-replicated ``(block, 128)`` scratch; every tile of the sweep then
+compares those against the lane-oriented column tile with no per-tile
+relayout.  Counts and stats accumulate lane-partial in ``(block, 128)``
+scratch (folding a tile's partner lanes into 128 is elementwise), and the
+last tile of the sweep reduces them into lane-dense ``(1, block)`` output
+blocks.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -43,14 +57,32 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+
+_COMBINE = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+_REDUCE = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
+
+
+def _ident_value(dtype, reduce):
+    """Reduce identity of ``dtype`` as a Python scalar (int8 atoms carry
+    int8 identities — the host-side stat decode maps them back, DESIGN.md
+    §15)."""
+    if jnp.issubdtype(dtype, jnp.integer):
+        info = np.iinfo(dtype)
+        return int(info.max if reduce == "min" else info.min)
+    return float(np.inf if reduce == "min" else -np.inf)
+
 
 def _ident(dtype, reduce):
-    """Reduce identity in the array's OWN dtype (int8 atoms carry int8
-    identities — the host-side stat decode maps them back, DESIGN.md §15)."""
-    if jnp.issubdtype(dtype, jnp.integer):
-        info = jnp.iinfo(dtype)
-        return jnp.array(info.max if reduce == "min" else info.min, dtype)
-    return jnp.array(np.inf if reduce == "min" else -np.inf, dtype)
+    """Reduce identity in the array's OWN dtype."""
+    return jnp.array(_ident_value(dtype, reduce), dtype)
+
+
+def _wide(dtype):
+    """The 32-bit dtype a (possibly encoded) column is compared in.  Every
+    encoding widens exactly (int8/int16 -> int32, bf16 -> f32), so
+    predicate outcomes and extrema are those of the narrow values."""
+    return jnp.int32 if jnp.issubdtype(dtype, jnp.integer) else jnp.float32
 
 
 def _tile_possible(op, lmin, lmax, rmin, rmax):
@@ -115,203 +147,44 @@ def _empty_role_outputs(n, r_cols, reduces):
     return count, stats
 
 
-def _stitch(outs, row_ids, block, npad, n, r_cols, reduces):
+def _stitch(outs, row_ids, block, npad, n, dtypes, reduces):
     """Scatter worklist-compact outputs back to full row width: rows in
     unlaunched blocks take count 0 / the reduce identity (what the dense
-    grid gives scoped-out rows)."""
+    grid gives scoped-out rows).  Stats arrive widened and leave in
+    ``dtypes`` (the columns' own); the cast is exact."""
     nb = npad // block
     if row_ids.size == nb:  # dense row coverage: outputs are already in order
-        return outs[0][:n], [s[:n] for s in outs[1:]]
+        return outs[0][:n], [
+            s[:n].astype(dt) for s, dt in zip(outs[1:], dtypes)
+        ]
     ridx = jnp.asarray(
         (row_ids[:, None] * block + np.arange(block)[None, :]).reshape(-1)
     )
     count = jnp.zeros((npad,), jnp.int32).at[ridx].set(outs[0])[:n]
     stats = [
-        jnp.full((npad,), _ident(c.dtype, red), c.dtype).at[ridx].set(s)[:n]
-        for s, c, red in zip(outs[1:], r_cols, reduces)
+        jnp.full((npad,), _ident_value(dt, red), s.dtype)
+        .at[ridx].set(s)[:n].astype(dt)
+        for s, dt, red in zip(outs[1:], dtypes, reduces)
     ]
     return count, stats
 
 
 def _block_bounds(vals, scope, reduce, nb, block):
     """Scope-masked per-block bounds (identity outside scope keeps the
-    ``@pl.when`` pruning sound)."""
+    ``@pl.when`` pruning sound), widened to the 32-bit SMEM word."""
     ident = _ident(vals.dtype, reduce)
     masked = jnp.where(scope > 0, vals, ident)
     resh = masked.reshape(nb, block)
-    return jnp.min(resh, axis=1) if reduce == "min" else jnp.max(resh, axis=1)
+    red = jnp.min(resh, axis=1) if reduce == "min" else jnp.max(resh, axis=1)
+    return red.astype(_wide(vals.dtype))
 
 
-# --------------------------------------------------------- single-role kernel
-def _role_kernel(
-    ops: Tuple[str, ...],
-    reduces: Tuple[str, ...],
-    bm: int,
-    bn: int,
-    ncols: int,
-    *refs,
-):
-    n_atoms = len(ops)
-    # scalar-prefetch refs first (the worklist id arrays), then
-    # l[a] (bm,), r[a] (bn,), rs (bm,), cs (bn,),
-    # lmin[a] lmax[a] rmin[a] rmax[a] (1,) each, out: count (bm,), stat[a] (bm,)
-    it = iter(refs)
-
-    def take(count):
-        return tuple(next(it) for _ in range(count))
-
-    (rid_ref,) = take(1)
-    (cid_ref,) = take(1)
-    lv = take(n_atoms)
-    r = take(n_atoms)
-    (rs,) = take(1)
-    (cs,) = take(1)
-    lmin = take(n_atoms)
-    lmax = take(n_atoms)
-    rmin = take(n_atoms)
-    rmax = take(n_atoms)
-    (count_ref,) = take(1)
-    stat_refs = take(n_atoms)
-
-    g = pl.program_id(0)
-
-    @pl.when(g % ncols == 0)
-    def _init():
-        count_ref[...] = jnp.zeros_like(count_ref)
-        for a in range(n_atoms):
-            stat_refs[a][...] = jnp.full_like(
-                stat_refs[a], _ident(stat_refs[a].dtype, reduces[a])
-            )
-
-    # ---- tile pruning from prefetched block bounds (paper's partition
-    # pruning): every atom must be satisfiable somewhere in the tile.
-    possible = jnp.bool_(True)
-    for a, op in enumerate(ops):
-        possible = possible & _tile_possible(
-            op, lmin[a][0], lmax[a][0], rmin[a][0], rmax[a][0]
-        )
-
-    @pl.when(possible)
-    def _compute():
-        # row/col ids are GLOBAL indices read from the prefetched worklist:
-        # the diagonal exclusion compares untranslated positions no matter
-        # which tile pairs actually launch.
-        row_ids = rid_ref[g // ncols] * bm + jax.lax.broadcasted_iota(
-            jnp.int32, (bm, bn), 0
-        )
-        col_ids = cid_ref[g % ncols] * bn + jax.lax.broadcasted_iota(
-            jnp.int32, (bm, bn), 1
-        )
-        viol = (
-            (rs[...] > 0)[:, None]
-            & (cs[...] > 0)[None, :]
-            & (row_ids != col_ids)
-        )
-        for a, op in enumerate(ops):
-            viol = viol & _cmp(op, lv[a][...][:, None], r[a][...][None, :])
-        count_ref[...] += jnp.sum(viol.astype(jnp.int32), axis=1)
-        for a, red in enumerate(reduces):
-            ident = _ident(stat_refs[a].dtype, red)
-            vals = jnp.where(viol, r[a][...][None, :], ident)
-            tile = jnp.min(vals, axis=1) if red == "min" else jnp.max(vals, axis=1)
-            stat_refs[a][...] = (
-                jnp.minimum(stat_refs[a][...], tile)
-                if red == "min"
-                else jnp.maximum(stat_refs[a][...], tile)
-            )
-
-
-def _worklist_specs(bm, bn, ncols):
-    """BlockSpecs for a 1-D worklist launch: row-side inputs index through
-    the prefetched ``rid`` array, col-side through ``cid``; outputs are
-    compact over the worklist's row order (stitched back host-side).
-    Returns ``(row, col, bound_row, bound_col, out)`` specs for callers to
-    compose in their own operand order."""
-    row_spec = pl.BlockSpec((bm,), lambda g, rid, cid: (rid[g // ncols],))
-    col_spec = pl.BlockSpec((bn,), lambda g, rid, cid: (cid[g % ncols],))
-    bound_i = pl.BlockSpec((1,), lambda g, rid, cid: (rid[g // ncols],))
-    bound_j = pl.BlockSpec((1,), lambda g, rid, cid: (cid[g % ncols],))
-    out_spec = pl.BlockSpec((bm,), lambda g, rid, cid: (g // ncols,))
-    return row_spec, col_spec, bound_i, bound_j, out_spec
-
-
-def dc_role_scan_pallas(
-    l_cols: Sequence[jnp.ndarray],
-    r_cols: Sequence[jnp.ndarray],
-    ops: Sequence[str],
-    row_scope: jnp.ndarray,
-    col_scope: jnp.ndarray,
-    reduces: Sequence[str],
-    block: int = 256,
-    interpret: bool = False,
-    row_blocks: Optional[Tuple[int, int]] = None,
-    col_blocks: Optional[Tuple[int, int]] = None,
-    row_block_ids=None,
-    col_block_ids=None,
-) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
-    """Blocked theta-join violation scan, one role (see module docstring).
-
-    Shapes are padded to a multiple of ``block``; padded rows are scoped out.
-
-    ``row_block_ids`` / ``col_block_ids`` are the block-sparse worklist
-    entry (DESIGN.md §15): only the cross product of the given row and col
-    block ids is launched — the executor passes the ledger's cold block
-    geometry so checked x checked tile pairs never launch.  ``row_blocks``
-    / ``col_blocks`` are the contiguous ``(lo, hi)`` sugar (the §11 strip
-    entry and §12 ingest-delta entry); they resolve to ``arange``
-    worklists.  Rows outside the launched blocks get count 0 and the
-    reduce identity, exactly as if they were scoped out."""
-    n_atoms = len(ops)
-    n = l_cols[0].shape[0]
-    bm = bn = block
-    nb = -(-n // block)
-    npad = nb * block
-    rid = resolve_block_ids(nb, row_blocks, row_block_ids)
-    cid = resolve_block_ids(nb, col_blocks, col_block_ids)
-    if rid.size == 0 or cid.size == 0:
-        return _empty_role_outputs(n, r_cols, reduces)
-    nrows, ncols = rid.size, cid.size
-
-    def pad1(x, fill=0):
-        return jnp.pad(x, (0, npad - n), constant_values=fill)
-
-    rs = pad1(row_scope).astype(jnp.int32)
-    cs = pad1(col_scope).astype(jnp.int32)
-    lp = [pad1(c) for c in l_cols]
-    rp = [pad1(c) for c in r_cols]
-
-    lmin = [_block_bounds(c, rs, "min", nb, block) for c in lp]
-    lmax = [_block_bounds(c, rs, "max", nb, block) for c in lp]
-    rmin = [_block_bounds(c, cs, "min", nb, block) for c in rp]
-    rmax = [_block_bounds(c, cs, "max", nb, block) for c in rp]
-
-    row_s, col_s, b_i, b_j, out_s = _worklist_specs(bm, bn, ncols)
-    in_specs = (
-        [row_s] * n_atoms + [col_s] * n_atoms + [row_s, col_s]
-        + [b_i] * 2 * n_atoms + [b_j] * 2 * n_atoms
-    )
-    out_shape = [jax.ShapeDtypeStruct((nrows * block,), jnp.int32)] + [
-        jax.ShapeDtypeStruct((nrows * block,), c.dtype) for c in r_cols
-    ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nrows * ncols,),
-        in_specs=in_specs,
-        out_specs=[out_s] * (1 + n_atoms),
-    )
-    kernel = functools.partial(
-        _role_kernel, tuple(ops), tuple(reduces), bm, bn, ncols
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(
-        jnp.asarray(rid), jnp.asarray(cid),
-        *lp, *rp, rs, cs, *lmin, *lmax, *rmin, *rmax,
-    )
-    return _stitch(outs, rid, block, npad, n, r_cols, reduces)
+def _fold_lanes(x, reduce, lanes):
+    """(rows, k * lanes) -> (rows, lanes): combine the lane groups
+    elementwise — the cross-lane part of the reduction waits for the end of
+    the row's sweep."""
+    parts = [x[:, k * lanes:(k + 1) * lanes] for k in range(x.shape[1] // lanes)]
+    return functools.reduce(_COMBINE[reduce], parts)
 
 
 # ---------------------------------------------------------- fused-role kernel
@@ -322,9 +195,11 @@ def _pair_kernel(
     t2_reduces: Tuple[str, ...],
     l_idx: Tuple[int, ...],
     r_idx: Tuple[int, ...],
+    t1_idents: Tuple[float, ...],
+    t2_idents: Tuple[float, ...],
     n_distinct: int,
-    bm: int,
-    bn: int,
+    block: int,
+    lanes: int,
     ncols: int,
     *refs,
 ):
@@ -340,85 +215,101 @@ def _pair_kernel(
 
     (rid_ref,) = take(1)
     (cid_ref,) = take(1)
-    rowv = take(n_distinct)  # distinct columns, row-side tiles
+    rowv = take(n_distinct)  # distinct columns, row-side (1, block) tiles
     (rs,) = take(1)
-    colv = take(n_distinct)  # distinct columns, col-side tiles
+    colv = take(n_distinct)  # distinct columns, col-side (1, block) tiles
     (cs,) = take(1)
-    row_min = take(n_distinct)  # per-block bounds under the ROW scope
+    row_min = take(n_distinct)  # whole (nb,) SMEM bounds under the ROW scope
     row_max = take(n_distinct)
-    col_min = take(n_distinct)  # per-block bounds under the COL scope
+    col_min = take(n_distinct)  # ... under the COL scope
     col_max = take(n_distinct)
-    (t1_count_ref,) = take(1)
+    (t1_count_ref,) = take(1)  # (1, block) lane-dense outputs
     (t2_count_ref,) = take(1)
     t1_stat_refs = take(n_atoms)
     t2_stat_refs = take(n_atoms)
+    row_t = take(n_distinct)  # (block, lanes) scratch: row values per sublane
+    (rs_t,) = take(1)
+    (t1_acc,) = take(1)  # (block, lanes) lane-partial accumulators
+    (t2_acc,) = take(1)
+    t1_part = take(n_atoms)
+    t2_part = take(n_atoms)
 
     g = pl.program_id(0)
+    r = rid_ref[g // ncols]
+    c = cid_ref[g % ncols]
+
+    def to_sublanes(tile):
+        """(1, block) -> (block, lanes) with out[i, :] == tile[0, i]."""
+        return jnp.broadcast_to(tile, (lanes, block)).T
 
     @pl.when(g % ncols == 0)
-    def _init():
-        t1_count_ref[...] = jnp.zeros_like(t1_count_ref)
-        t2_count_ref[...] = jnp.zeros_like(t2_count_ref)
-        for a in range(n_atoms):
-            t1_stat_refs[a][...] = jnp.full_like(
-                t1_stat_refs[a], _ident(t1_stat_refs[a].dtype, t1_reduces[a])
-            )
-            t2_stat_refs[a][...] = jnp.full_like(
-                t2_stat_refs[a], _ident(t2_stat_refs[a].dtype, t2_reduces[a])
-            )
+    def _start_row():
+        for d in range(n_distinct):
+            row_t[d][...] = to_sublanes(rowv[d][...].astype(row_t[d].dtype))
+        rs_t[...] = to_sublanes(rs[...])
+        t1_acc[...] = jnp.zeros_like(t1_acc)
+        t2_acc[...] = jnp.zeros_like(t2_acc)
+        for part, ident in zip(t1_part + t2_part, t1_idents + t2_idents):
+            part[...] = jnp.full(part.shape, ident, part.dtype)
 
     possible1 = jnp.bool_(True)
     possible2 = jnp.bool_(True)
     for a, (op, fop) in enumerate(zip(ops, flipped)):
         li, ri = l_idx[a], r_idx[a]
         possible1 = possible1 & _tile_possible(
-            op, row_min[li][0], row_max[li][0], col_min[ri][0], col_max[ri][0]
+            op, row_min[li][r], row_max[li][r], col_min[ri][c], col_max[ri][c]
         )
         possible2 = possible2 & _tile_possible(
-            fop, row_min[ri][0], row_max[ri][0], col_min[li][0], col_max[li][0]
+            fop, row_min[ri][r], row_max[ri][r], col_min[li][c], col_max[li][c]
         )
 
-    row_ids = rid_ref[g // ncols] * bm + jax.lax.broadcasted_iota(
-        jnp.int32, (bm, bn), 0
-    )
-    col_ids = cid_ref[g % ncols] * bn + jax.lax.broadcasted_iota(
-        jnp.int32, (bm, bn), 1
-    )
-    base = (
-        (rs[...] > 0)[:, None]
-        & (cs[...] > 0)[None, :]
-        & (row_ids != col_ids)
-    )
+    def rows(ref):  # sublane-oriented row values, broadcast over the partners
+        return jnp.concatenate([ref[...]] * (block // lanes), axis=1)
 
-    def accumulate(viol, count_ref, stat_refs, stat_src, reduces):
-        count_ref[...] += jnp.sum(viol.astype(jnp.int32), axis=1)
+    def cols(d):  # lane-oriented partner values, broadcast over the rows
+        tile = colv[d][...].astype(row_t[d].dtype)
+        return jnp.broadcast_to(tile, (block, block))
+
+    def base():
+        row_ids = r * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 0
+        )
+        col_ids = c * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 1
+        )
+        in_scope = (rows(rs_t) > 0) & (jnp.broadcast_to(cs[...], (block, block)) > 0)
+        return in_scope & (row_ids != col_ids)
+
+    def accumulate(viol, acc, parts, stat_src, reduces, idents):
+        acc[...] += _fold_lanes(viol.astype(jnp.int32), "sum", lanes)
         for a, red in enumerate(reduces):
-            ident = _ident(stat_refs[a].dtype, red)
-            vals = jnp.where(viol, colv[stat_src[a]][...][None, :], ident)
-            tile = jnp.min(vals, axis=1) if red == "min" else jnp.max(vals, axis=1)
-            stat_refs[a][...] = (
-                jnp.minimum(stat_refs[a][...], tile)
-                if red == "min"
-                else jnp.maximum(stat_refs[a][...], tile)
+            vals = jnp.where(viol, cols(stat_src[a]), idents[a])
+            parts[a][...] = _COMBINE[red](
+                parts[a][...], _fold_lanes(vals, red, lanes)
             )
 
     @pl.when(possible1)
     def _role_t1():
-        viol = base
+        viol = base()
         for a, op in enumerate(ops):
-            viol = viol & _cmp(
-                op, rowv[l_idx[a]][...][:, None], colv[r_idx[a]][...][None, :]
-            )
-        accumulate(viol, t1_count_ref, t1_stat_refs, r_idx, t1_reduces)
+            viol = viol & _cmp(op, rows(row_t[l_idx[a]]), cols(r_idx[a]))
+        accumulate(viol, t1_acc, t1_part, r_idx, t1_reduces, t1_idents)
 
     @pl.when(possible2)
     def _role_t2():
-        viol = base
+        viol = base()
         for a, fop in enumerate(flipped):
-            viol = viol & _cmp(
-                fop, rowv[r_idx[a]][...][:, None], colv[l_idx[a]][...][None, :]
-            )
-        accumulate(viol, t2_count_ref, t2_stat_refs, l_idx, t2_reduces)
+            viol = viol & _cmp(fop, rows(row_t[r_idx[a]]), cols(l_idx[a]))
+        accumulate(viol, t2_acc, t2_part, l_idx, t2_reduces, t2_idents)
+
+    @pl.when(g % ncols == ncols - 1)
+    def _finish_row():  # (block, lanes) partials -> lane-dense (1, block)
+        for out, part, red in zip(
+            (t1_count_ref, t2_count_ref) + t1_stat_refs + t2_stat_refs,
+            (t1_acc, t2_acc) + t1_part + t2_part,
+            ("sum", "sum") + t1_reduces + t2_reduces,
+        ):
+            out[...] = _REDUCE[red](part[...].T, axis=0, keepdims=True)
 
 
 def distinct_columns(
@@ -460,14 +351,24 @@ def dc_pair_scan_pallas(
 ):
     """Fused BOTH-role scan: one worklist launch computes the t1 detection
     (atoms as written) and the t2 detection (``flipped`` atoms) over the
-    same tiles — the relax→detect role scans that used to be two separate
-    launches over identical tile pairs (DESIGN.md §15).
+    same tiles (DESIGN.md §15).
+
+    Shapes are padded to a multiple of ``block``; padded rows are scoped
+    out.  ``row_block_ids`` / ``col_block_ids`` are the block-sparse
+    worklist entry: only the cross product of the given row and col block
+    ids is launched.  ``row_blocks`` / ``col_blocks`` are the contiguous
+    ``(lo, hi)`` sugar (the §11 strip entry and §12 ingest-delta entry);
+    they resolve to ``arange`` worklists.  Rows outside the launched blocks
+    get count 0 and the reduce identity, exactly as if they were scoped
+    out.  Compiled for the chip, ``block`` must be a multiple of 128 (one
+    lane row per tile).
 
     Returns ``(t1_count, t1_stats, t2_count, t2_stats)``, each full row
-    width, bit-identical to two ``dc_role_scan`` launches."""
+    width, bit-identical to ``ref.dc_pair_scan``."""
+    if not interpret and block % LANES:
+        raise ValueError(f"block {block} must be a multiple of {LANES} on TPU")
     n_atoms = len(ops)
     n = l_cols[0].shape[0]
-    bm = bn = block
     nb = -(-n // block)
     npad = nb * block
     rid = resolve_block_ids(nb, row_blocks, row_block_ids)
@@ -477,12 +378,16 @@ def dc_pair_scan_pallas(
         t2c, t2s = _empty_role_outputs(n, l_cols, t2_reduces)
         return t1c, t1s, t2c, t2s
     nrows, ncols = rid.size, cid.size
+    lanes = math.gcd(block, LANES)
 
     distinct, l_idx, r_idx = distinct_columns(l_cols, r_cols)
     n_distinct = len(distinct)
 
-    def pad1(x, fill=0):
-        return jnp.pad(x, (0, npad - n), constant_values=fill)
+    def pad1(x):
+        return jnp.pad(x, (0, npad - n))
+
+    def tiles(x):  # lane-dense tile rows
+        return x.reshape(nb, 1, block)
 
     rs = pad1(row_scope).astype(jnp.int32)
     cs = pad1(col_scope).astype(jnp.int32)
@@ -492,25 +397,41 @@ def dc_pair_scan_pallas(
     col_min = [_block_bounds(c, cs, "min", nb, block) for c in dp]
     col_max = [_block_bounds(c, cs, "max", nb, block) for c in dp]
 
-    row_s, col_s, b_i, b_j, out_s = _worklist_specs(bm, bn, ncols)
+    tile_shape = (pl.Squeezed(), 1, block)
+    row_s = pl.BlockSpec(tile_shape, lambda g, rid, cid: (rid[g // ncols], 0, 0))
+    col_s = pl.BlockSpec(tile_shape, lambda g, rid, cid: (cid[g % ncols], 0, 0))
+    bound_s = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out_s = pl.BlockSpec(tile_shape, lambda g, rid, cid: (g // ncols, 0, 0))
     in_specs = (
-        [row_s] * n_distinct + [row_s] + [col_s] * n_distinct + [col_s]
-        + [b_i] * 2 * n_distinct + [b_j] * 2 * n_distinct
+        [row_s] * (n_distinct + 1) + [col_s] * (n_distinct + 1)
+        + [bound_s] * 4 * n_distinct
     )
-    out_shape = (
-        [jax.ShapeDtypeStruct((nrows * block,), jnp.int32)] * 2
-        + [jax.ShapeDtypeStruct((nrows * block,), c.dtype) for c in r_cols]
-        + [jax.ShapeDtypeStruct((nrows * block,), c.dtype) for c in l_cols]
+    # stats accumulate and leave the kernel in the widened dtype; _stitch
+    # casts them back to the column's own dtype
+    stat_dtypes = [c.dtype for c in r_cols] + [c.dtype for c in l_cols]
+    out_shape = [jax.ShapeDtypeStruct((nrows, 1, block), jnp.int32)] * 2 + [
+        jax.ShapeDtypeStruct((nrows, 1, block), _wide(dt)) for dt in stat_dtypes
+    ]
+    scratch = (
+        [pltpu.VMEM((block, lanes), _wide(c.dtype)) for c in distinct]
+        + [pltpu.VMEM((block, lanes), jnp.int32)] * 3
+        + [pltpu.VMEM((block, lanes), _wide(dt)) for dt in stat_dtypes]
+    )
+    idents = tuple(
+        _ident_value(dt, red)
+        for dt, red in zip(stat_dtypes, tuple(t1_reduces) + tuple(t2_reduces))
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nrows * ncols,),
         in_specs=in_specs,
         out_specs=[out_s] * (2 + 2 * n_atoms),
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _pair_kernel, tuple(ops), tuple(flipped), tuple(t1_reduces),
-        tuple(t2_reduces), l_idx, r_idx, n_distinct, bm, bn, ncols,
+        tuple(t2_reduces), l_idx, r_idx, idents[:n_atoms], idents[n_atoms:],
+        n_distinct, block, lanes, ncols,
     )
     outs = pl.pallas_call(
         kernel,
@@ -519,15 +440,16 @@ def dc_pair_scan_pallas(
         interpret=interpret,
     )(
         jnp.asarray(rid), jnp.asarray(cid),
-        *dp, rs, *dp, cs, *row_min, *row_max, *col_min, *col_max,
+        *[tiles(c) for c in dp], tiles(rs), *[tiles(c) for c in dp], tiles(cs),
+        *row_min, *row_max, *col_min, *col_max,
     )
-    # outs order mirrors out_shape: t1_count, t2_count, t1_stats, t2_stats
+    flat = [o.reshape(-1) for o in outs]
     t1c, t1s = _stitch(
-        (outs[0],) + tuple(outs[2:2 + n_atoms]), rid, block, npad, n,
-        r_cols, t1_reduces,
+        [flat[0]] + flat[2:2 + n_atoms], rid, block, npad, n,
+        stat_dtypes[:n_atoms], t1_reduces,
     )
     t2c, t2s = _stitch(
-        (outs[1],) + tuple(outs[2 + n_atoms:]), rid, block, npad, n,
-        l_cols, t2_reduces,
+        [flat[1]] + flat[2 + n_atoms:], rid, block, npad, n,
+        stat_dtypes[n_atoms:], t2_reduces,
     )
     return t1c, t1s, t2c, t2s

@@ -20,7 +20,6 @@ import numpy as np
 from repro.kernels import ref
 from repro.kernels.dc_pairs import (
     dc_pair_scan_pallas,
-    dc_role_scan_pallas,
     distinct_columns,
     resolve_block_ids,
 )
@@ -38,83 +37,41 @@ def _mode(force: str | None) -> str:
     return "pallas" if on_tpu() else "ref"
 
 
-def dc_role_scan(
-    l_cols: Sequence[jnp.ndarray],
-    r_cols: Sequence[jnp.ndarray],
-    ops: Sequence[str],
-    row_scope: jnp.ndarray,
-    col_scope: jnp.ndarray,
-    reduces: Sequence[str],
-    block: int = 256,
-    force: str | None = None,
-    row_blocks: Tuple[int, int] | None = None,
-    col_blocks: Tuple[int, int] | None = None,
-    row_block_ids=None,
-    col_block_ids=None,
-) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
-    """``row_blocks=(lo, hi)`` launches only that strip of row blocks — the
-    partition-strip entry the work ledger schedules (DESIGN.md §11).
-    ``col_blocks`` is the symmetric partner-side restriction: the
-    ingest-delta entry scanning against only fresh rows (DESIGN.md §12).
-    ``row_block_ids`` / ``col_block_ids`` generalize both to an arbitrary
-    block-id worklist (DESIGN.md §15): only the cross product of the given
-    row and col blocks is launched."""
-    mode = _mode(force)
-    restr = dict(
-        row_blocks=row_blocks, col_blocks=col_blocks,
-        row_block_ids=row_block_ids, col_block_ids=col_block_ids,
-    )
-    if mode == "ref":
-        return ref.dc_role_scan(
-            l_cols, r_cols, ops, row_scope, col_scope, reduces, block=block,
-            **restr,
-        )
-    return dc_role_scan_pallas(
-        l_cols,
-        r_cols,
-        ops,
-        row_scope,
-        col_scope,
-        reduces,
-        block=block,
-        interpret=(mode == "interpret"),
-        **restr,
-    )
-
-
 class TileStats(NamedTuple):
     """Launch geometry + modeled HBM traffic of one DC scan (DESIGN.md §15).
 
     ``bytes_moved`` is computed from the launch geometry and the ACTUAL
     operand dtypes (so compressed encodings show up as fewer bytes) — a
-    deterministic model of tile DMA traffic, not a hardware counter, which
-    keeps the CI gates reproducible on any backend.
+    deterministic model of the kernel's DMA traffic (``_scan_bytes``), not
+    a hardware counter, which keeps the CI gates reproducible on any
+    backend.
     """
 
     launched: int  # tile pairs actually launched (the worklist size)
     total: int  # tile pairs a dense scan would launch (nb x nb)
-    bytes_moved: int  # modeled bytes DMA'd by the launched tiles
+    bytes_moved: int  # modeled bytes DMA'd by the launch
 
 
-def _tile_bytes(
+def _scan_bytes(
     distinct: Sequence[jnp.ndarray],
-    l_cols: Sequence[jnp.ndarray],
-    r_cols: Sequence[jnp.ndarray],
+    n_atoms: int,
     block: int,
+    nrows: int,
+    ncols: int,
+    nb: int,
 ) -> int:
-    """Modeled per-tile DMA traffic of the fused scan: each DISTINCT atom
-    column loads one row tile + one col tile (the fusion contract — shared
-    columns are not re-fetched per role), scopes load both sides, per-block
-    bounds are scalars, and each tile visit writes both roles' outputs."""
-    col_bytes = sum(block * c.dtype.itemsize for c in distinct)
-    scope_bytes = 2 * block * 4
-    bound_bytes = 4 * sum(c.dtype.itemsize for c in distinct)
-    out_bytes = (
-        2 * block * 4
-        + sum(block * c.dtype.itemsize for c in r_cols)
-        + sum(block * c.dtype.itemsize for c in l_cols)
-    )
-    return 2 * col_bytes + scope_bytes + bound_bytes + out_bytes
+    """Modeled HBM traffic of one fused launch over an ``nrows x ncols``
+    worklist (DESIGN.md §15).  Each DISTINCT atom column moves one tile per
+    side in its own dtype (the fusion contract — shared columns are not
+    re-fetched per role), and each side's scope moves an int32 tile.  Col
+    tiles change at every grid step; row tiles and the lane-dense outputs
+    (2 counts + both roles' stats, widened to 32 bits) change once per
+    worklist row; the whole per-block bound arrays (4 per distinct column,
+    32-bit) are copied into SMEM once per launch."""
+    side = sum(block * c.dtype.itemsize for c in distinct) + block * 4
+    out = (2 + 2 * n_atoms) * block * 4
+    bounds = 4 * len(distinct) * nb * 4
+    return nrows * ncols * side + nrows * (side + out) + bounds
 
 
 class DCPairScanResult(NamedTuple):
@@ -158,7 +115,10 @@ def dc_pair_scan(
     tiles = TileStats(
         launched=launched,
         total=nb * nb,
-        bytes_moved=launched * _tile_bytes(distinct, l_cols, r_cols, block),
+        bytes_moved=(
+            _scan_bytes(distinct, len(ops), block, rid.size, cid.size, nb)
+            if launched else 0
+        ),
     )
     mode = _mode(force)
     restr = dict(

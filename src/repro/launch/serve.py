@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 
 import numpy as np
@@ -139,7 +140,10 @@ def run_decode(args) -> None:
         print(f"  req {r.rid}: prompt {len(r.prompt)} toks -> {r.out[:8]}...")
 
 
-def run_queries(opts: ServeOptions) -> None:
+def run_queries(opts: ServeOptions):
+    """Serve the synthetic exploratory workload and print its summary.
+    Returns the ``QueryServer`` (its ``snapshot()`` and ``daisy``) so a
+    caller can judge the run."""
     import threading
 
     from repro.core.constraints import Atom, DC, FD
@@ -315,6 +319,19 @@ def run_queries(opts: ServeOptions) -> None:
         print(f"  trace: {len(events)} spans -> {opts.trace} "
               f"(Perfetto-loadable; {tracer.dropped} dropped)")
         print(format_rollup(rollup(events)))
+    return server
+
+
+def served_failures(snap, submitted: int) -> list:
+    """Why a served run failed, empty when every ticket was answered
+    without error: a ticket error is surfaced to its caller and the server
+    keeps serving, so the run as a whole must check."""
+    failures = []
+    if snap["errors"]:
+        failures.append(f"{snap['errors']} tickets raised")
+    if snap["answered"] != submitted:
+        failures.append(f"answered {snap['answered']} of {submitted} queries")
+    return failures
 
 
 def main():
@@ -368,8 +385,14 @@ def main():
     )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.workload == "queries":
-        run_queries(ServeOptions.from_args(args))
+        server = run_queries(ServeOptions.from_args(args))
+        failures = served_failures(server.snapshot(), args.requests)
+        if failures:
+            sys.exit("serving failed: " + "; ".join(failures))
     else:
         run_decode(args)
 

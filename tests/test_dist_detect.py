@@ -364,8 +364,10 @@ def test_sharded_detect_on_mesh_subprocess():
     res = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_TEST],
         capture_output=True, text=True, timeout=1200,
+        # the child must never reach for an accelerator the parent may hold
         env={"PYTHONPATH": os.path.join(repo_root, "src"),
-             "PATH": os.environ.get("PATH", "/usr/bin:/bin")},
+             "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+             "JAX_PLATFORMS": "cpu"},
         cwd=repo_root,
     )
     assert "SUBPROCESS_OK" in res.stdout, res.stdout + "\n" + res.stderr

@@ -15,7 +15,7 @@ from repro.core.operators import Pred, Query
 from repro.core.relation import make_relation
 from repro.kernels import ops as kops
 from repro.kernels import ref
-from repro.kernels.dc_pairs import dc_role_scan_pallas, resolve_block_ids
+from repro.kernels.dc_pairs import dc_pair_scan_pallas, resolve_block_ids
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.semijoin import semijoin_pallas
 
@@ -23,6 +23,26 @@ SETTINGS = dict(max_examples=10, deadline=None)
 
 
 # ------------------------------------------------------------------ dc_pairs
+def _fused_scan(cols, ops, rs, cs, block, force, **restr):
+    """Both-role scan of the DC ``t1.c op t2.c`` over each column in turn."""
+    flipped = tuple(flip_op(op) for op in ops)
+    return kops.dc_pair_scan(
+        cols, cols, tuple(ops), flipped, rs, cs,
+        tuple(_T1_REDUCE[op] for op in ops),
+        tuple(_T1_REDUCE[op] for op in flipped),
+        block=block, force=force, **restr,
+    )
+
+
+def _assert_scans_equal(got, want):
+    for g, w in zip(
+        (got.t1_count, *got.t1_stat, got.t2_count, *got.t2_stat),
+        (want.t1_count, *want.t1_stat, want.t2_count, *want.t2_stat),
+    ):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 class TestDCPairsKernel:
     @pytest.mark.parametrize("n", [7, 64, 130, 300])
     @pytest.mark.parametrize("block", [64, 128])
@@ -32,12 +52,10 @@ class TestDCPairsKernel:
         b = jnp.asarray(rng.integers(0, 50, n).astype(np.int32))
         rs = jnp.asarray(rng.random(n) < 0.7)
         cs = jnp.asarray(rng.random(n) < 0.7)
-        args = ([a, b], [a, b], ["<", ">"], rs, cs, ["max", "min"])
-        c_ref, s_ref = ref.dc_role_scan(*args, block=block)
-        c_pal, s_pal = dc_role_scan_pallas(*args, block=block, interpret=True)
-        np.testing.assert_array_equal(np.asarray(c_ref), np.asarray(c_pal))
-        for r, p in zip(s_ref, s_pal):
-            np.testing.assert_array_equal(np.asarray(r), np.asarray(p))
+        args = ([a, b], ["<", ">"], rs, cs, block)
+        _assert_scans_equal(
+            _fused_scan(*args, "interpret"), _fused_scan(*args, "ref")
+        )
 
     @pytest.mark.parametrize("ops", [["<"], ["<=", ">="], ["==", "!="]])
     def test_op_sweep_float(self, ops):
@@ -46,13 +64,37 @@ class TestDCPairsKernel:
         cols = [jnp.asarray(rng.uniform(0, 10, n).astype(np.float32)) for _ in ops]
         rs = jnp.asarray(np.ones(n, bool))
         cs = jnp.asarray(np.ones(n, bool))
-        reduces = ["max" if o in ("<", "<=") else "min" for o in ops]
-        args = (cols, cols, ops, rs, cs, reduces)
-        c_ref, s_ref = ref.dc_role_scan(*args, block=32)
-        c_pal, s_pal = dc_role_scan_pallas(*args, block=32, interpret=True)
-        np.testing.assert_array_equal(np.asarray(c_ref), np.asarray(c_pal))
-        for r, p in zip(s_ref, s_pal):
-            np.testing.assert_allclose(np.asarray(r), np.asarray(p))
+        args = (cols, ops, rs, cs, 32)
+        _assert_scans_equal(
+            _fused_scan(*args, "interpret"), _fused_scan(*args, "ref")
+        )
+
+    @pytest.mark.parametrize(
+        "dtype", [np.int8, np.int16, jnp.bfloat16, np.float32]
+    )
+    def test_encoded_dtypes_match_ref(self, dtype):
+        """Narrow (encoded) tiles are widened in VMEM; counts and stats —
+        returned in the column's own dtype — match the oracle's."""
+        rng = np.random.default_rng(11)
+        n = 200
+        cols = [
+            jnp.asarray(rng.integers(-20, 20, n).astype(np.float32)).astype(dtype)
+            for _ in range(2)
+        ]
+        rs = jnp.asarray(rng.random(n) < 0.8)
+        cs = jnp.asarray(rng.random(n) < 0.8)
+        args = (cols, ["<", ">="], rs, cs, 64)
+        _assert_scans_equal(
+            _fused_scan(*args, "interpret"), _fused_scan(*args, "ref")
+        )
+
+    def test_compiled_block_must_fill_lanes(self):
+        a = jnp.arange(64, dtype=jnp.int32)
+        with pytest.raises(ValueError, match="multiple of 128"):
+            dc_pair_scan_pallas(
+                [a], [a], ["<"], [">"], a > 0, a > 0, ["max"], ["min"],
+                block=64,
+            )
 
     def test_count_matches_brute_force(self):
         rng = np.random.default_rng(9)
@@ -82,11 +124,10 @@ class TestDCPairsKernel:
         a = jnp.asarray(rng.integers(0, 8, n).astype(np.int32))
         rs = jnp.asarray(rng.random(n) < 0.5)
         cs = jnp.asarray(rng.random(n) < 0.5)
-        args = ([a], [a], ["<"], rs, cs, ["max"])
-        c_ref, s_ref = ref.dc_role_scan(*args, block=32)
-        c_pal, s_pal = dc_role_scan_pallas(*args, block=32, interpret=True)
-        np.testing.assert_array_equal(np.asarray(c_ref), np.asarray(c_pal))
-        np.testing.assert_array_equal(np.asarray(s_ref[0]), np.asarray(s_pal[0]))
+        args = ([a], ["<"], rs, cs, 32)
+        _assert_scans_equal(
+            _fused_scan(*args, "interpret"), _fused_scan(*args, "ref")
+        )
 
 
 # ------------------------------------------------- block-sparse worklist (§15)

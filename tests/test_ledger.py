@@ -131,6 +131,13 @@ class TestStripUnionIdentity:
 
 # ------------------------------------------------- strip-scoped kernel entry
 class TestStripScopedScan:
+    @staticmethod
+    def _scan(col, row_scope, col_scope, block, force, **restr):
+        return kops.dc_pair_scan(
+            [col], [col], ["<"], [">"], row_scope, col_scope, ["max"],
+            ["min"], block=block, force=force, **restr,
+        )
+
     @pytest.mark.parametrize("force", ["ref", "interpret"])
     def test_row_blocks_matches_masked_full_scan(self, force):
         rng = np.random.default_rng(0)
@@ -140,29 +147,23 @@ class TestStripScopedScan:
         for lo, hi in ((0, 1), (1, 3), (3, 5), (0, 5)):
             strip_mask = np.zeros(n, bool)
             strip_mask[lo * block : hi * block] = True
-            want_c, want_s = kops.dc_role_scan(
-                [cols[0]], [cols[0]], ["<"],
-                scope & strip_mask, scope, ["max"], block=block, force=force,
-            )
-            got_c, got_s = kops.dc_role_scan(
-                [cols[0]], [cols[0]], ["<"],
-                scope & strip_mask, scope, ["max"], block=block, force=force,
+            want = self._scan(cols[0], scope & strip_mask, scope, block, force)
+            got = self._scan(
+                cols[0], scope & strip_mask, scope, block, force,
                 row_blocks=(lo, hi),
             )
-            np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
-            np.testing.assert_array_equal(
-                np.asarray(got_s[0]), np.asarray(want_s[0])
-            )
+            for g, w in zip(
+                (got.t1_count, got.t1_stat[0], got.t2_count, got.t2_stat[0]),
+                (want.t1_count, want.t1_stat[0], want.t2_count, want.t2_stat[0]),
+            ):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
     def test_row_blocks_validation(self):
         rng = np.random.default_rng(1)
         col = rng.integers(0, 5, 16).astype(np.int32)
         scope = np.ones(16, bool)
         with pytest.raises(ValueError):
-            kops.dc_role_scan(
-                [col], [col], ["<"], scope, scope, ["max"], block=8,
-                force="ref", row_blocks=(1, 5),
-            )
+            self._scan(col, scope, scope, 8, "ref", row_blocks=(1, 5))
 
 
 # --------------------------------------------------------------- the ledger

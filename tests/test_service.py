@@ -10,6 +10,7 @@ example.
 import json
 import os
 import runpy
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -326,3 +327,61 @@ def test_example_serve_queries_runs(capsys):
     )
     out = capsys.readouterr().out
     assert "cache" in out
+
+
+# ------------------------------------------------------------ the serve CLI
+class TestServeCLI:
+    ARGV = ["serve", "--workload", "queries", "--rows", "64",
+            "--requests", "4", "--sessions", "2"]
+
+    def test_errored_tickets_fail_the_run(self, monkeypatch):
+        """A ticket error is surfaced to its caller and the server keeps
+        serving — the run as a whole must still exit non-zero."""
+        from repro.launch import compile_cache, serve
+
+        def boom(self, query):
+            raise RuntimeError("injected execute failure")
+
+        monkeypatch.setattr(compile_cache, "configure_compile_cache", lambda: "")
+        monkeypatch.setattr(Daisy, "execute", boom)
+        monkeypatch.setattr("sys.argv", self.ARGV)
+        with pytest.raises(SystemExit) as exc:
+            serve.main()
+        assert "4 tickets raised" in str(exc.value.code)
+        assert "answered 0 of 4" in str(exc.value.code)
+
+    def test_clean_run_exits_zero(self, monkeypatch, capsys):
+        from repro.launch import compile_cache, serve
+
+        monkeypatch.setattr(compile_cache, "configure_compile_cache", lambda: "")
+        monkeypatch.setattr("sys.argv", self.ARGV)
+        serve.main()
+        assert "served 4 queries" in capsys.readouterr().out
+
+    def test_served_failures(self):
+        from repro.launch.serve import served_failures
+
+        assert served_failures({"errors": 0, "answered": 5}, 5) == []
+        assert served_failures({"errors": 0, "answered": 4}, 5) == [
+            "answered 4 of 5 queries"
+        ]
+
+
+def test_compile_cache_placement(monkeypatch):
+    """The environment variable wins and nothing is set in code; without
+    it the cache sits at one fixed path inside the checkout."""
+    import jax
+
+    from repro.launch import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(cc.ENV_VAR, "/placed/by/the/caller")
+    assert cc.configure_compile_cache() == "/placed/by/the/caller"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv(cc.ENV_VAR)
+    try:
+        assert cc.configure_compile_cache() == str(cc.CHECKOUT_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(cc.CHECKOUT_CACHE)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert cc.CHECKOUT_CACHE == Path(ROOT) / ".jax_cache"
