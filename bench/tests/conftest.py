@@ -1,0 +1,9 @@
+"""The benchmark's own tests: ``python -m pytest bench/tests`` from the
+repository root, on the CPU (``JAX_PLATFORMS=cpu``)."""
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
+sys.path.insert(0, str(BENCH.parent / "src"))
