@@ -42,7 +42,7 @@ from repro.core.constraints import DC, FD
 from repro.core.cost import CostModel, sharded_detect_cost
 from repro.core.detect import detect_auto, detect_fd
 from repro.core.ledger import TABLE_ROWS_RULE, WorkLedger
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, host_reads, to_host
 from repro.core.operators import (
     GroupBySpec,
     JoinState,
@@ -191,9 +191,10 @@ class Daisy:
         self.db = dict(db)
         self.rules = {t: list(rs) for t, rs in rules.items()}
         self.config = config or DaisyConfig()
-        # observability seam (DESIGN.md §13): spans around every clean phase
-        # (relax / detect / repair / mark), execute, and ingest.  Defaults to
-        # the strict no-op tracer, so untraced runs pay only the call site.
+        # observability seam (DESIGN.md §13): spans around execute and its
+        # phases (plan / step / filter / join / groupby), every clean phase
+        # (relax / detect / repair / mark), and ingest.  Defaults to the
+        # strict no-op tracer, so untraced runs pay only the call site.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.stats: Dict[Tuple[str, str], object] = {}
         self.cost: Dict[Tuple[str, str], CostModel] = {}
@@ -221,7 +222,7 @@ class Daisy:
             for rule in rs:
                 self.ledger.register(
                     table, rule.name, self.db[table].capacity,
-                    np.asarray(self.cold_rows(table, rule.name)),
+                    to_host(self.cold_rows(table, rule.name)),
                 )
 
     @property
@@ -231,6 +232,15 @@ class Daisy:
         answer is a pure function of the instance — the cache soundness
         contract, asserted in tests/test_service.py)."""
         return self._clean_version
+
+    @property
+    def host_syncs(self) -> int:
+        """Device-to-host reads made through ``repro.obs.to_host`` so far:
+        the count the ``syncs`` span attrs charge (DESIGN.md §13).  The
+        counter is process-wide — every executor, server and cleaner
+        thread in the process adds to it — so read it as a delta around
+        the work to attribute."""
+        return host_reads()
 
     @property
     def lock(self) -> threading.RLock:
@@ -270,7 +280,7 @@ class Daisy:
             self._clean_version += 1
             rel = mark_checked(rel, rule_name, scope)
             self.ledger.commit(
-                table, rule_name, np.asarray(self._cold_mask(rel, table, rule_name))
+                table, rule_name, to_host(self._cold_mask(rel, table, rule_name))
             )
             cm = self.cost.get((table, rule_name))
             if cm is not None:
@@ -282,7 +292,7 @@ class Daisy:
         """Precompute per-(table, rule) statistics (§5.2.3, §7/Fig 11)."""
         for table, rules in self.rules.items():
             rel = self.db[table]
-            n = int(np.asarray(rel.num_rows()))
+            n = int(to_host(rel.num_rows()))
             for rule in rules:
                 key = (table, rule.name)
                 if isinstance(rule, FD):
@@ -314,7 +324,7 @@ class Daisy:
         (histories and the switched flag survive: an append changes the
         economics of FUTURE work, not what already happened)."""
         rel = self.db[table]
-        n = int(np.asarray(rel.num_rows()))
+        n = int(to_host(rel.num_rows()))
         for rule in self.rules.get(table, ()):
             key = (table, rule.name)
             cm = self.cost.get(key)
@@ -375,7 +385,7 @@ class Daisy:
                 ch = rel.checked.get(rule.name)
                 if ch is None:
                     continue
-                ch_np = np.asarray(ch)
+                ch_np = to_host(ch)
                 if ch_np.any():
                     had_checked[rule.name] = ch_np
                     if isinstance(rule, FD):
@@ -386,7 +396,7 @@ class Daisy:
                         )
                         old_dirty[rule.name] = np.asarray(dirty, dtype=bool)
             new_rel, start = append_rows(rel, rows)
-            n_new = int(np.asarray(new_rel.valid).sum()) - start
+            n_new = int(to_host(new_rel.valid).sum()) - start
             report = IngestReport(
                 table=table, rows=n_new, start=start,
                 capacity_before=cap_before, capacity=new_rel.capacity,
@@ -406,7 +416,7 @@ class Daisy:
                     checked = np.pad(checked, (0, cap - checked.shape[0]))
                 if od is not None and od.shape[0] < cap:
                     od = np.pad(od, (0, cap - od.shape[0]))
-                cold = np.asarray(self._cold_mask(new_rel, table, rule.name))
+                cold = to_host(self._cold_mask(new_rel, table, rule.name))
                 scope = self.ledger.record_ingest(
                     table, rule.name, cap, cold, start, hi,
                     checked=checked, old_dirty=od,
@@ -475,7 +485,7 @@ class Daisy:
         if scope is None or scope.capacity < cap:
             scope = self.ledger.register(
                 table, rule_name, cap,
-                np.asarray(self.cold_rows(table, rule_name)),
+                to_host(self.cold_rows(table, rule_name)),
             )
         return scope.cold_count
 
@@ -494,17 +504,17 @@ class Daisy:
         different candidate sets than the foreground path (DESIGN.md §10).
         ``prefer`` front-loads groups intersecting that mask (the freshly
         ingested strips, DESIGN.md §12) ahead of the ascending sweep."""
-        valid = np.asarray(rel.valid)
-        cold_np = np.asarray(cold)
+        valid = to_host(rel.valid)
+        cold_np = to_host(cold)
         gid = np.zeros(valid.shape[0], dtype=np.int64)
         for attr in fd.lhs:
-            _, inv = np.unique(np.asarray(rel.columns[attr]), return_inverse=True)
+            _, inv = np.unique(to_host(rel.columns[attr]), return_inverse=True)
             gid = gid * (int(inv.max()) + 1) + inv
         # densify the combined key so per-group sizes are one bincount pass
         _, gid = np.unique(gid, return_inverse=True)
         cold_groups = np.unique(gid[cold_np])
         if prefer is not None:
-            pref = np.unique(gid[np.asarray(prefer) & cold_np])
+            pref = np.unique(gid[to_host(prefer) & cold_np])
             rest = cold_groups[~np.isin(cold_groups, pref)]
             cold_groups = np.concatenate([pref, rest])
         if max_rows is not None:
@@ -549,7 +559,7 @@ class Daisy:
             pending_rep = self._process_pending(table, rule, report)
             rel = self.db[table]
             cold = self.cold_rows(table, rule_name)
-            if not bool(np.asarray(jnp.any(cold))):
+            if not bool(to_host(jnp.any(cold))):
                 return pending_rep
             if isinstance(rule, FD):
                 scope_l = self.ledger.scope(table, rule_name)
@@ -570,7 +580,7 @@ class Daisy:
                 # rule appended to a live Daisy (lazily-created scope) hands
                 # the strip engine its real cold strips
                 scope = self.ledger.register(
-                    table, rule_name, rel.capacity, np.asarray(cold)
+                    table, rule_name, rel.capacity, to_host(cold)
                 )
                 strips = scope.cold_strips(fresh_first=True)
                 if max_strips is not None:
@@ -649,7 +659,7 @@ class Daisy:
                 rel, valid=rel.valid & jnp.asarray(pos < ent.hi)
             )
             seed = fresh & rel_hi.valid
-            if not bool(np.asarray(jnp.any(seed))):
+            if not bool(to_host(jnp.any(seed))):
                 continue
             self.detect_calls += 1
             res = relax_fd(
@@ -657,9 +667,9 @@ class Daisy:
                 max_iters=self.config.max_relax_iters, use_rhs=True,
             )
             scope = (seed | res.extra) & rel_hi.valid
-            scope_n = int(np.asarray(jnp.sum(scope)))
-            rep.answer_size += int(np.asarray(jnp.sum(seed)))
-            rep.extra += int(np.asarray(jnp.sum(res.extra)))
+            scope_n = int(to_host(jnp.sum(scope)))
+            rep.answer_size += int(to_host(jnp.sum(seed)))
+            rep.extra += int(to_host(jnp.sum(res.extra)))
             rep.detect_pairs += scope_n  # group-by is O(scope)
             self.detect_pairs += scope_n
             lhs_cols = [rel.columns[a] for a in fd.lhs]
@@ -690,14 +700,14 @@ class Daisy:
                 (t_full, full_v, full_n,
                  *((lfull_v, lfull_n) if lhs_single else (None, None))),
             ):
-                if not bool(np.asarray(jnp.any(rows_mask))):
+                if not bool(to_host(jnp.any(rows_mask))):
                     continue
                 deltas.append((fd.rhs, Candidates(rv, rn, kinds, rows_mask)))
                 if lv is not None:
                     deltas.append((fd.lhs[0], Candidates(lv, ln, kinds, rows_mask)))
             if deltas:
                 self.repair_calls += 1
-                rep.repaired += int(np.asarray(jnp.sum(t_fresh | t_full)))
+                rep.repaired += int(to_host(jnp.sum(t_fresh | t_full)))
                 self.db[table] = self._apply(rel, deltas, table, fd.name)
 
     def _ingest_delta_dc(
@@ -721,18 +731,18 @@ class Daisy:
             checked[: min(c.shape[0], cap)] = c[:cap]
             fresh = jnp.asarray((pos >= ent.lo) & (pos < ent.hi))
             row_scope = jnp.asarray(checked) & rel.valid
-            if not bool(np.asarray(jnp.any(row_scope & rel.valid))):
+            if not bool(to_host(jnp.any(row_scope & rel.valid))):
                 continue
             row_block_ids = self._active_blocks(row_scope)
             col_blocks = (ent.lo // block, -(-ent.hi // block))
-            rep.answer_size += int(np.asarray(jnp.sum(fresh & rel.valid)))
+            rep.answer_size += int(to_host(jnp.sum(fresh & rel.valid)))
             # dense scan only: the sharded path has no partner-side
             # restriction, and a delta is small by construction
             rel, det = self._dc_detect_repair(
                 rel, dc, row_scope, fresh, None, None, cm, rep,
                 col_blocks=col_blocks, row_block_ids=row_block_ids,
             )
-            rep.repaired += int(np.asarray(jnp.sum(
+            rep.repaired += int(to_host(jnp.sum(
                 ((det.t1_count > 0) | (det.t2_count > 0)) & row_scope
             )))
             self.db[table] = rel
@@ -768,23 +778,23 @@ class Daisy:
             # checked already or statically clean (detection over them merges
             # nothing), which is exactly what the unshrunk scan committed.
             cold = self._cold_mask(rel, table, fd.name)
-            if bool(np.asarray(jnp.any(cold))):
+            if bool(to_host(jnp.any(cold))):
                 scope = self._fd_increment_seed(rel, fd, cold, None)
             else:
                 scope = rel.valid
             mark_scope = rel.valid
-            rep.answer_size = int(np.asarray(jnp.sum(scope)))
+            rep.answer_size = int(to_host(jnp.sum(scope)))
         else:
             answer = (
                 answer_override
                 if answer_override is not None
                 else filter_mask(rel, step.preds)
             )
-            rep.answer_size = int(np.asarray(jnp.sum(answer)))
+            rep.answer_size = int(to_host(jnp.sum(answer)))
             # Fig. 11 skip: answer touches no dirty group and nothing unchecked
             if st is not None:
                 dirty_hit = bool(
-                    np.asarray(
+                    to_host(
                         jnp.any(answer & jnp.asarray(st.dirty_row) & unchecked(rel, fd.name))
                     )
                 )
@@ -805,13 +815,13 @@ class Daisy:
                     use_rhs=step.use_rhs,
                 )
                 scope = answer | res.extra
-                rep.extra = int(np.asarray(jnp.sum(res.extra)))
-                rep.relax_iterations = int(np.asarray(res.iterations))
-                rep.relax_converged = bool(np.asarray(res.converged))
+                rep.extra = int(to_host(jnp.sum(res.extra)))
+                rep.relax_iterations = int(to_host(res.iterations))
+                rep.relax_converged = bool(to_host(res.converged))
                 sp.set(extra=rep.extra, iterations=rep.relax_iterations)
 
         repair_scope = scope & unchecked(rel, fd.name)
-        if not bool(np.asarray(jnp.any(repair_scope))):
+        if not bool(to_host(jnp.any(repair_scope))):
             # everything in scope already checked for this rule (e.g. the
             # post-clean query phase of the offline baseline) — skip the
             # detection/repair/merge entirely.
@@ -822,7 +832,7 @@ class Daisy:
             return
         mesh = self._detect_mesh(step)
         self.detect_calls += 1
-        rep.detect_pairs = int(np.asarray(jnp.sum(scope)))  # group-by is O(scope)
+        rep.detect_pairs = int(to_host(jnp.sum(scope)))  # group-by is O(scope)
         self.detect_pairs += rep.detect_pairs
         with self.tracer.span(
             "clean.detect", rule=fd.name, table=table, mode=rep.mode,
@@ -840,7 +850,7 @@ class Daisy:
         self.repair_calls += 1
         with self.tracer.span("clean.repair", rule=fd.name, table=table) as sp:
             deltas = fd_repair_candidates(rel, fd, det, repair_scope)
-            rep.repaired = int(np.asarray(jnp.sum(det.violated & repair_scope)))
+            rep.repaired = int(to_host(jnp.sum(det.violated & repair_scope)))
             rel = self._apply(rel, deltas, table, fd.name)
             sp.set(repaired=rep.repaired)
         rel = self._mark(
@@ -848,7 +858,7 @@ class Daisy:
         )
         self.db[table] = rel
         if cm and record_cost:
-            d_i = float(np.asarray(jnp.sum(scope)))
+            d_i = float(to_host(jnp.sum(scope)))
             cm.record(rep.answer_size, rep.extra, d_i, rep.repaired)
             if step.mode == "full":
                 cm.mark_switched()
@@ -876,8 +886,8 @@ class Daisy:
         launch geometry.  Returns ``(rel, detect_result)``."""
         table = rep.table
         self.detect_calls += 1
-        rows = int(np.asarray(jnp.sum(row_scope & rel.valid)))
-        cols = int(np.asarray(jnp.sum(col_scope & rel.valid)))
+        rows = int(to_host(jnp.sum(row_scope & rel.valid)))
+        cols = int(to_host(jnp.sum(col_scope & rel.valid)))
         rep.detect_pairs += rows * cols
         self.detect_pairs += rows * cols
         with self.tracer.span(
@@ -927,7 +937,7 @@ class Daisy:
         (None for an empty mask) — the block-sparse worklist side for
         answer-shaped scans (DESIGN.md §15): blocks between two active runs
         are absent from the launch, not merely scope-pruned inside it."""
-        idx = np.flatnonzero(np.asarray(mask))
+        idx = np.flatnonzero(to_host(mask))
         if idx.size == 0:
             return None
         return np.unique(idx // self.config.dc_block).astype(np.int32)
@@ -968,8 +978,8 @@ class Daisy:
         answer = filter_mask(rel, step.preds) if step.preds else rel.valid
         mode = step.mode
         if mode == "auto" and st is not None:
-            answer_size = int(np.asarray(jnp.sum(answer)))
-            pivot_vals = np.asarray(rel.columns[st.pivot])[np.asarray(answer)]
+            answer_size = int(to_host(jnp.sum(answer)))
+            pivot_vals = to_host(rel.columns[st.pivot])[to_host(answer)]
             dec = statsmod.algorithm2_decide(
                 st,
                 pivot_vals,
@@ -1013,7 +1023,7 @@ class Daisy:
             else:
                 row_scope = jnp.zeros_like(rel.valid)
         rep.mode = mode if mode != "strip" else rep.mode
-        rep.answer_size = int(np.asarray(jnp.sum(row_scope if mode == "strip" else answer)))
+        rep.answer_size = int(to_host(jnp.sum(row_scope if mode == "strip" else answer)))
 
         # idempotence gate (the DC analogue of the FD dirty-group skip): when
         # everything this step would scope is already checked for the rule,
@@ -1022,7 +1032,7 @@ class Daisy:
         # candidate support and advancing clean_version for no state change.
         # Repeated queries therefore skip, keeping answers version-stable
         # (the service cache's contract, DESIGN.md §9).
-        if not bool(np.asarray(jnp.any(row_scope))):
+        if not bool(to_host(jnp.any(row_scope))):
             rep.mode = "skipped"
             report.steps.append(rep)
             if cm and record_cost:
@@ -1038,7 +1048,7 @@ class Daisy:
             row_block_ids=row_block_ids,
         )
         repaired = (det.t1_count > 0) | (det.t2_count > 0)
-        rep.repaired = int(np.asarray(jnp.sum(repaired & row_scope)))
+        rep.repaired = int(to_host(jnp.sum(repaired & row_scope)))
 
         if mode == "incremental":
             # partners of the answer (the DC-correlated tuples, §4.2) get
@@ -1052,7 +1062,7 @@ class Daisy:
                 col_block_ids=self._active_blocks(answer),
             )
             rep.extra = int(
-                np.asarray(
+                to_host(
                     jnp.sum(((det2.t1_count > 0) | (det2.t2_count > 0)) & partner_scope)
                 )
             )
@@ -1074,10 +1084,22 @@ class Daisy:
     # ------------------------------------------------------------ execution
     def _run_steps(self, plan: PlanInfo, report: ExecReport) -> None:
         for step in plan.steps:
-            if isinstance(step.rule, FD):
-                self._clean_fd(step, report)
-            else:
-                self._clean_dc(step, report)
+            # one phase span per planned step, around its gates, Algorithm
+            # 2, cold masks and clean.* spans (never inside one of those)
+            with self.tracer.span(
+                "execute.step", rule=step.rule.name, mode=step.mode
+            ) as sp:
+                if isinstance(step.rule, FD):
+                    self._clean_fd(step, report)
+                else:
+                    self._clean_dc(step, report)
+                if self.tracer:
+                    ran = report.steps[-1].mode
+                    skipped = ran == "skipped"
+                    sp.set(
+                        mode=step.mode if skipped else ran,
+                        outcome="skipped" if skipped else "cleaned",
+                    )
 
     def execute(self, query: Query) -> DaisyResult:
         # re-entrant: many serving sessions may share one executor; the lock
@@ -1087,11 +1109,12 @@ class Daisy:
         with self._lock, self.tracer.span(
             "daisy.execute", table=query.table, joins=len(query.joins)
         ) as sp:
-            plan = plan_query(
-                query, self.rules, self._want_full(),
-                lemma1_fast_path=self.config.lemma1_fast_path,
-                ledger=self.ledger,
-            )
+            with self.tracer.span("execute.plan"):
+                plan = plan_query(
+                    query, self.rules, self._want_full(),
+                    lemma1_fast_path=self.config.lemma1_fast_path,
+                    ledger=self.ledger,
+                )
             report = ExecReport(notes=list(plan.notes))
 
             if not query.joins:
@@ -1105,11 +1128,13 @@ class Daisy:
     def _execute_sp(self, query: Query, plan: PlanInfo, report: ExecReport) -> DaisyResult:
         self._run_steps(plan, report)
         rel = self.db[query.table]
-        mask = filter_mask(rel, query.preds)
-        report.result_size = int(np.asarray(jnp.sum(mask)))
+        with self.tracer.span("execute.filter"):
+            mask = filter_mask(rel, query.preds)
+            report.result_size = int(to_host(jnp.sum(mask)))
         result = DaisyResult(mask=mask, report=report)
         if query.groupby is not None:
-            result.groups = self._groupby_sp(rel, mask, query.groupby)
+            with self.tracer.span("execute.groupby"):
+                result.groups = self._groupby_sp(rel, mask, query.groupby)
         return result
 
     def _groupby_sp(self, rel: Relation, mask, spec: GroupBySpec):
@@ -1120,29 +1145,33 @@ class Daisy:
     # --------------------------------------------------------- join queries
     def _execute_join(self, query: Query, plan: PlanInfo, report: ExecReport) -> DaisyResult:
         # pre-clean qualifying masks (the dirty base join inputs)
-        pre_masks: Dict[str, jnp.ndarray] = {
-            query.table: filter_mask(self.db[query.table], query.preds)
-        }
-        for j in query.joins:
-            pre_masks[j.right] = filter_mask(self.db[j.right], j.right_preds)
+        with self.tracer.span("execute.filter", stage="pre"):
+            pre_masks: Dict[str, jnp.ndarray] = {
+                query.table: filter_mask(self.db[query.table], query.preds)
+            }
+            for j in query.joins:
+                pre_masks[j.right] = filter_mask(self.db[j.right], j.right_preds)
 
         # clean each side's qualifying part (push-down, §5.1)
         self._run_steps(plan, report)
 
-        post_masks: Dict[str, jnp.ndarray] = {
-            query.table: filter_mask(self.db[query.table], query.preds)
-        }
-        for j in query.joins:
-            post_masks[j.right] = filter_mask(self.db[j.right], j.right_preds)
+        with self.tracer.span("execute.filter", stage="post"):
+            post_masks: Dict[str, jnp.ndarray] = {
+                query.table: filter_mask(self.db[query.table], query.preds)
+            }
+            for j in query.joins:
+                post_masks[j.right] = filter_mask(self.db[j.right], j.right_preds)
 
-        state: Optional[JoinState] = None
-        for j in query.joins:
-            state = self._join_once(query, state, j, pre_masks, post_masks, report)
-        report.result_size = int(np.asarray(jnp.sum(state.valid)))
-        report.recheck_violations = self._recheck(state)
+        with self.tracer.span("execute.join", joins=len(query.joins)):
+            state: Optional[JoinState] = None
+            for j in query.joins:
+                state = self._join_once(query, state, j, pre_masks, post_masks, report)
+            report.result_size = int(to_host(jnp.sum(state.valid)))
+            report.recheck_violations = self._recheck(state)
         result = DaisyResult(join=state, report=report)
         if query.groupby is not None:
-            result.groups = self._groupby_join(state, query.groupby)
+            with self.tracer.span("execute.groupby"):
+                result.groups = self._groupby_join(state, query.groupby)
         return result
 
     def _key_source(self, state: Optional[JoinState], base: str, col: str) -> str:
@@ -1197,7 +1226,7 @@ class Daisy:
             order = jnp.argsort(~v, stable=True)[: cfg.join_capacity]
             li, ri, v = li[order], ri[order], v[order]
             overflow = ovf | ovf2 | ovf3
-            report.join_overflow = bool(np.asarray(overflow))
+            report.join_overflow = bool(to_host(overflow))
             return JoinState(
                 tables=(left_table, j.right),
                 rows={left_table: li, j.right: ri},
@@ -1220,7 +1249,7 @@ class Daisy:
             for t, r in state.rows.items()
         }
         new_rows[j.right] = jnp.where(v, ri, rel_r.capacity)
-        report.join_overflow = report.join_overflow or bool(np.asarray(ovf))
+        report.join_overflow = report.join_overflow or bool(to_host(ovf))
         return JoinState(
             tables=state.tables + (j.right,),
             rows=new_rows,
@@ -1242,7 +1271,7 @@ class Daisy:
                     self.detect_calls += 1
                     det = detect_fd(rel, rule, used & rel.valid, k=self.config.k)
                     fresh = det.violated & unchecked(rel, rule.name)
-                    total += int(np.asarray(jnp.sum(fresh)))
+                    total += int(to_host(jnp.sum(fresh)))
         return total
 
     def _groupby_join(self, state: JoinState, spec: GroupBySpec):

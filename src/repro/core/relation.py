@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import to_host
+
 # Sentinel pushed to the end of sorts; also the "invalid" key. Encoded values
 # produced by Dictionary start at 0 and stay well below this.
 SENTINEL = np.int32(2**31 - 1)
@@ -340,9 +342,9 @@ def append_rows(rel: Relation, data: Mapping[str, np.ndarray]) -> Tuple[Relation
         raise ValueError(f"ragged ingest batch: column lengths {sorted(lengths)}")
     n_new = lengths.pop()
     if n_new == 0:
-        return rel, int(np.asarray(rel.valid).sum())
+        return rel, int(to_host(rel.valid).sum())
 
-    start = int(np.asarray(rel.valid).sum())
+    start = int(to_host(rel.valid).sum())
     needed = start + n_new
     if needed > rel.capacity:
         rel = _grow_relation(rel, next_pow2(needed))

@@ -32,6 +32,7 @@ import numpy as np
 from repro.core.constraints import DC, FD
 from repro.core.detect import detect_fd
 from repro.core.relation import Relation
+from repro.obs.trace import to_host
 
 
 class FDStats(NamedTuple):
@@ -44,11 +45,11 @@ class FDStats(NamedTuple):
 def fd_stats(rel: Relation, fd: FD) -> FDStats:
     """Precompute the per-rule group-by statistics (host-side arrays)."""
     det = detect_fd(rel, fd, rel.valid)
-    dirty = np.asarray(det.violated)
+    dirty = to_host(det.violated)
     eps = int(dirty.sum())
-    distinct = np.asarray((det.rhs_count > 0).sum(axis=1))
+    distinct = to_host((det.rhs_count > 0).sum(axis=1))
     p_est = float(distinct[dirty].mean()) if eps else 1.0
-    return FDStats(dirty, eps, p_est, int(np.asarray(rel.num_rows())))
+    return FDStats(dirty, eps, p_est, int(to_host(rel.num_rows())))
 
 
 class DCStats(NamedTuple):
@@ -64,8 +65,8 @@ def dc_stats(rel: Relation, dc: DC, p: int = 16) -> DCStats:
     attribute's value range, estimate per-partition-pair conflicts from
     boundary overlaps of the remaining atoms."""
     pivot = dc.atoms[0].left
-    vals = {a: np.asarray(rel.columns[a]) for a in dc.attrs}
-    valid = np.asarray(rel.valid)
+    vals = {a: to_host(rel.columns[a]) for a in dc.attrs}
+    valid = to_host(rel.valid)
     pv = vals[pivot][valid]
     n = int(valid.sum())
     # quantile partitions over the pivot (the matrix row/col ranges)

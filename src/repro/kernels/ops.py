@@ -25,6 +25,7 @@ from repro.kernels.dc_pairs import (
 )
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.semijoin import semijoin_pallas
+from repro.obs.trace import to_host
 
 
 def on_tpu() -> bool:
@@ -179,7 +180,7 @@ def _eligible_kinds(arr: np.ndarray) -> set:
         return kinds
     if np.all(arr == np.floor(arr)) and arr.min() >= -128 and arr.max() <= 127:
         kinds.add("int8")
-    rt = np.asarray(jnp.asarray(arr).astype(jnp.bfloat16).astype(arr.dtype))
+    rt = to_host(jnp.asarray(arr).astype(jnp.bfloat16).astype(arr.dtype))
     if np.array_equal(rt, arr):
         kinds.add("bf16")
     return kinds
@@ -195,7 +196,7 @@ def plan_dc_encodings(
     when nothing compresses (all ``orig``) so callers can skip the encode
     pass entirely.  Planning is host-side numpy over the base columns —
     O(n) per attribute, noise next to the O(n^2/block) scan it feeds."""
-    host = {a: np.asarray(c) for a, c in cols.items()}
+    host = {a: to_host(c) for a, c in cols.items()}
     eligible = {a: _eligible_kinds(arr) for a, arr in host.items()}
     # code: every atom touching the attr is a same-attribute equality atom
     # (and the column is NaN-free — code(NaN) == code(NaN) would flip !=)
@@ -258,7 +259,7 @@ def encode_column(col: jnp.ndarray, enc: ColumnEncoding) -> jnp.ndarray:
     if enc.kind == "bf16":
         return col.astype(jnp.bfloat16)
     if enc.kind == "code":
-        codes = np.searchsorted(enc.table, np.asarray(col))
+        codes = np.searchsorted(enc.table, to_host(col))
         return jnp.asarray(codes.astype(enc.code_dtype))
     raise ValueError(enc.kind)
 

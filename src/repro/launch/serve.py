@@ -285,8 +285,7 @@ def run_queries(opts: ServeOptions):
         print(
             f"  background: {bg['increments']} increments "
             f"({bg['detect_calls']} detect / {bg['repair_calls']} repair, "
-            f"{bg['scopes_completed']} scopes warmed, {bg['yields']} yields) "
-            f"serving idle fraction {snap['idle_fraction']:.0%}"
+            f"{bg['scopes_completed']} scopes warmed, {bg['yields']} yields)"
         )
         for scope, prog in snap["ledger"].items():
             print(

@@ -5,8 +5,9 @@
 Perfetto / ``chrome://tracing``: one complete-event (``"ph": "X"``) per
 span with microsecond timestamps relative to the tracer's creation, one
 track per recording thread (plus synthetic tracks like the server's
-queue-wait), and the span attrs under ``args``.  ``events_from_chrome``
-inverts it, so a dumped trace round-trips back into ``SpanEvent``s for
+queue-wait), and the span attrs under ``args`` (with the span's
+``span_id`` and ``parent_id`` when set).  ``events_from_chrome`` inverts
+it, so a dumped trace round-trips back into ``SpanEvent``s for
 offline analysis (tools/trace_summary.py).
 
 ``rollup`` is the per-phase cost attribution: for every span name, the
@@ -25,6 +26,9 @@ import json
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from repro.obs.trace import SpanEvent
+
+# span identity, carried in a trace event's ``args`` beside the attrs
+IDS = ("span_id", "parent_id")
 
 
 def chrome_trace(events: Iterable[SpanEvent], origin: float = 0.0) -> Dict:
@@ -46,6 +50,9 @@ def chrome_trace(events: Iterable[SpanEvent], origin: float = 0.0) -> Dict:
             "tid": tid,
             "args": dict(ev.attrs),
         }
+        for key in IDS:
+            if getattr(ev, key):
+                rec["args"][key] = getattr(ev, key)
         if ev.dur > 0.0:
             rec["ph"] = "X"
             rec["dur"] = ev.dur * 1e6
@@ -75,12 +82,15 @@ def events_from_chrome(trace: Dict) -> List[SpanEvent]:
     for rec in trace.get("traceEvents", ()):
         if rec.get("ph") not in ("X", "i"):
             continue
+        attrs = dict(rec.get("args", {}))
+        ids = {key: int(attrs.pop(key, 0)) for key in IDS}
         out.append(SpanEvent(
             name=rec["name"],
             t0=rec["ts"] / 1e6,
             dur=rec.get("dur", 0.0) / 1e6,
             thread=names.get(rec.get("tid"), str(rec.get("tid"))),
-            attrs=dict(rec.get("args", {})),
+            attrs=attrs,
+            **ids,
         ))
     return out
 

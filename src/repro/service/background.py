@@ -48,12 +48,11 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-import numpy as np
-
 from repro.core.constraints import FD
 from repro.core.cost import ScopePriority, prioritize_scopes, sharded_detect_cost
 from repro.core.executor import Daisy, StepReport
 from repro.core.ledger import TABLE_ROWS_RULE
+from repro.obs.trace import to_host
 from repro.service.metrics import ServiceMetrics
 
 
@@ -158,7 +157,7 @@ class BackgroundCleaner:
                 cm = daisy.cost.get((table, rule_name))
                 info = daisy.sharded_info.get((table, rule_name))
                 n = int(cm.n) if cm is not None else int(
-                    np.asarray(daisy.db[table].num_rows())
+                    to_host(daisy.db[table].num_rows())
                 )
                 scope_ledger = daisy.ledger.scope(table, rule_name)
                 fresh_cold = (

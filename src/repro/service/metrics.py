@@ -4,7 +4,7 @@ sessions — now attributed between the foreground serving path and the
 background cleaner.
 
 Thread-safety contract: the foreground observers (``observe_hit``,
-``observe_execution``, ``observe_work``) and the step/idle counters are
+``observe_execution``, ``observe_work``) and the step counters are
 mutated by the single serving thread only; the background observers
 (``observe_background``, ``observe_bg_yield``, ``observe_ledger``) are
 mutated by the cleaner thread under ``_bg_lock``, and ``snapshot()``
@@ -26,15 +26,11 @@ few serialized ``StepReport`` dicts (``StepReport.asdict``) for
 drill-down, and — when latencies were observed — per-ticket-class
 p50/p95/p99 under ``"latency"`` (DESIGN.md §13).
 
-The two derived numbers the layer exists for:
-
-* ``detect_repair_per_query`` — *foreground* detect/repair invocations per
-  answered query, the paper's incremental-cleaning cost amortized by the
-  clean-state-aware cache AND by background warmup
-  (benchmarks/serve_bg_warmup.py gates that background cleaning strictly
-  lowers it against the same workload without it);
-* ``idle_fraction`` — share of serving wall-clock the step loop spent
-  waiting for work: the budget the background cleaner runs in.
+The derived number the layer exists for is ``detect_repair_per_query``:
+*foreground* detect/repair invocations per answered query, the paper's
+incremental-cleaning cost amortized by the clean-state-aware cache AND by
+background warmup (benchmarks/serve_bg_warmup.py gates that background
+cleaning strictly lowers it against the same workload without it).
 """
 
 from __future__ import annotations
@@ -80,7 +76,6 @@ class ServiceMetrics:
     ingests: int = 0
     ingested_rows: int = 0
     ingest_pending_deltas: int = 0  # rule scopes that queued an ingest-delta
-    serving_idle_s: float = 0.0  # step-loop time spent waiting for work
     # traffic shaping (DESIGN.md §14) — multi-writer, guarded by _bg_lock:
     # admission/shed/cancel happen on client threads, deadline accounting
     # on the serving thread
@@ -149,10 +144,6 @@ class ServiceMetrics:
         self.repair_calls += repair_delta
         self.tiles_launched += tiles_launched_delta
         self.tiles_skipped += tiles_skipped_delta
-
-    def observe_idle(self, seconds: float) -> None:
-        """Accumulate step-loop wait time (serving thread)."""
-        self.serving_idle_s += seconds
 
     def observe_ingest(self, report) -> None:
         """Record one served append from its ``IngestReport``
@@ -254,12 +245,6 @@ class ServiceMetrics:
         """Foreground cleaning work amortized per answered query."""
         return (self.detect_calls + self.repair_calls) / max(self.queries, 1)
 
-    @property
-    def idle_fraction(self) -> float:
-        """Share of elapsed wall-clock the step loop spent idle — the
-        background cleaner's available budget."""
-        return min(self.serving_idle_s / self.elapsed, 1.0)
-
     def snapshot(self) -> Dict[str, object]:
         """JSON-serializable counter snapshot with foreground/background
         attribution nested under ``foreground``/``background`` and
@@ -316,7 +301,6 @@ class ServiceMetrics:
             "queries_per_sec": round(self.queries_per_sec, 3),
             "hit_rate": round(self.hit_rate, 4),
             "detect_repair_per_query": round(self.detect_repair_per_query, 4),
-            "idle_fraction": round(self.idle_fraction, 4),
             "foreground": {
                 "detect_calls": self.detect_calls,
                 "repair_calls": self.repair_calls,

@@ -93,6 +93,9 @@ class Ticket:
     # perf_counter stamp set at submit: the serving thread derives queue-wait
     # spans and end-to-end latency histograms from it (DESIGN.md §13)
     submitted: float = 0.0
+    # perf_counter stamp set when ``QueryServer.step`` pops the ticket into
+    # a batch, only while tracing: splits its queue wait (DESIGN.md §13)
+    admitted: float = 0.0
     # traffic shaping (DESIGN.md §14)
     slo: str = "interactive"
     weight: float = 1.0

@@ -322,6 +322,13 @@ class QueryServer:
             self._inflight_batch = len(batch)
             if not batch:
                 self._idle.set()
+        if self.tracer:
+            # admission stamp: the queue-wait span's ``admit_s`` splits
+            # waiting for a step from waiting behind this batch's earlier
+            # tickets
+            now = time.perf_counter()
+            for t in batch:
+                t.admitted = now
         for t in dropped:  # cancelled while queued: no work was done
             self.metrics.observe_cancelled(t.slo)
         if not batch:
@@ -429,12 +436,15 @@ class QueryServer:
     def _record_queue_wait(self, ticket: Ticket) -> None:
         """Span from submit to the moment serving starts, on the synthetic
         "queue" track (it overlaps serving-thread spans, so it must not
-        break their nesting — obs/trace.py's thread contract)."""
+        break their nesting — obs/trace.py's thread contract).  ``admit_s``
+        is its part from submit to the step that popped the ticket into a
+        batch; the rest is the wait behind the batch's earlier tickets."""
         if ticket.submitted and self.tracer:
             now = time.perf_counter()
             self.tracer.record(
                 "serve.queue_wait", ticket.submitted, now - ticket.submitted,
                 thread="queue", seq=ticket.seq, kind=ticket.kind,
+                admit_s=ticket.admitted - ticket.submitted,
             )
 
     def _serve_ingest(self, ticket: Ticket) -> None:
@@ -486,8 +496,7 @@ class QueryServer:
         """Serving-thread loop: step while work arrives; exit once ``stop()``
         was called and the queue drained.  ``max_steps`` is a runaway
         backstop and counts only steps that served work — idling forever is
-        fine.  Idle wait time feeds the ``idle_fraction`` gauge (the
-        background cleaner's budget)."""
+        fine.  Idle waits are ``serve.idle`` spans when tracing."""
         served_steps = 0
         while served_steps < max_steps:
             if self.step():
@@ -497,9 +506,7 @@ class QueryServer:
                 if self._stopping and not len(self._queue):
                     return
                 with self.tracer.span("serve.idle"):
-                    t0 = time.perf_counter()
                     self._work.wait(timeout=idle_wait)
-                    self.metrics.observe_idle(time.perf_counter() - t0)
 
     def stop(self) -> None:
         """Refuse new submissions and wake the serving thread to exit after

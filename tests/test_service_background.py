@@ -438,4 +438,3 @@ def test_snapshot_background_attribution_serializable():
         snap["detect_calls"] + snap["background"]["detect_calls"]
         == daisy.detect_calls
     )
-    assert 0.0 <= snap["idle_fraction"] <= 1.0
