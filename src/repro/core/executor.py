@@ -34,6 +34,7 @@ import dataclasses
 import threading
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -66,6 +67,69 @@ from repro.core.relation import Relation, append_rows
 from repro.core.repair import Candidates, dc_repair_candidates, fd_repair_candidates
 from repro.core.setops import group_distinct_candidates
 from repro.core.update import apply_candidates, mark_checked, unchecked
+
+
+class _FilterCounts:
+    """Process-wide count of the answers the executor filtered anew and of
+    those it reused (``Daisy.filter_evals`` / ``filter_reuses``)."""
+
+    __slots__ = ("evals", "reuses", "_lock")
+
+    def __init__(self):
+        self.evals = self.reuses = 0
+        self._lock = threading.Lock()
+
+    def add(self, reused: bool) -> None:
+        with self._lock:
+            if reused:
+                self.reuses += 1
+            else:
+                self.evals += 1
+
+
+_FILTERS = _FilterCounts()
+
+
+@dataclasses.dataclass
+class _Answer:
+    """A step's answer mask, and its size once a gate or the final filter
+    has read it."""
+
+    mask: jnp.ndarray
+    size: Optional[int] = None
+
+
+class _AnswerMemo:
+    """The answer one ``execute`` computed last, keyed by the relation object
+    and the predicates it filtered: a step that leaves the relation as it
+    was (a skip) hands its answer to the next step and the final filter."""
+
+    __slots__ = ("rel", "key", "answer", "evals", "reuses")
+
+    def __init__(self):
+        self.rel = self.key = self.answer = None
+        self.evals = self.reuses = 0
+
+
+@jax.jit
+def _step_gate(answer, valid, checked, dirty=None, pivot=None):
+    """A clean step's skip gate as one program: the answer's size; ``hit``,
+    whether the answer holds a row the step would clean (unchecked for the
+    rule and, for an FD, in a dirty group: the Fig. 11 gate); and, given
+    Algorithm 2's pivot column, its extremes over the answer."""
+    live = valid if checked is None else valid & ~checked
+    hit = answer & live
+    if dirty is not None:
+        hit = hit & dirty
+    out = {"size": jnp.sum(answer), "hit": jnp.any(hit)}
+    if pivot is not None:
+        if jnp.issubdtype(pivot.dtype, jnp.floating):
+            lo, hi = -jnp.inf, jnp.inf
+        else:
+            lo, hi = jnp.iinfo(pivot.dtype).min, jnp.iinfo(pivot.dtype).max
+        out["lo"] = jnp.min(pivot, where=answer, initial=hi)
+        out["hi"] = jnp.max(pivot, where=answer, initial=lo)
+    return out
 
 
 def _blocks_attr(blocks) -> Optional[List[int]]:
@@ -197,7 +261,12 @@ class Daisy:
         # strict no-op tracer, so untraced runs pay only the call site.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.stats: Dict[Tuple[str, str], object] = {}
+        # each FD's dirty-row mask, resident on the device beside its stats
+        self._dirty_rows: Dict[Tuple[str, str], jnp.ndarray] = {}
         self.cost: Dict[Tuple[str, str], CostModel] = {}
+        # the answer the running ``execute`` computed last (None between
+        # queries, so no device buffer outlives one)
+        self._memo: Optional[_AnswerMemo] = None
         # serving hooks (DESIGN.md §9/§10): a monotone version counter bumped
         # on every candidate-merge / checked-bit commit (the service cache's
         # invalidation signal), cumulative detect/repair invocation and
@@ -241,6 +310,17 @@ class Daisy:
         thread in the process adds to it — so read it as a delta around
         the work to attribute."""
         return host_reads()
+
+    @property
+    def filter_evals(self) -> int:
+        """Answers the executor filtered anew, process-wide like
+        ``host_syncs`` (a miss's steps and final filter share one)."""
+        return _FILTERS.evals
+
+    @property
+    def filter_reuses(self) -> int:
+        """Answers the executor reused from the step before, process-wide."""
+        return _FILTERS.reuses
 
     @property
     def lock(self) -> threading.RLock:
@@ -296,9 +376,8 @@ class Daisy:
             for rule in rules:
                 key = (table, rule.name)
                 if isinstance(rule, FD):
-                    st = statsmod.fd_stats(rel, rule)
+                    st = self._set_fd_stats(key, rel, rule)
                     df = float(n)  # hash/sort group-by detection cost
-                    self.stats[key] = st
                     self.cost[key] = CostModel(
                         n=n,
                         epsilon=st.epsilon,
@@ -318,6 +397,12 @@ class Daisy:
                         expected_queries=self.config.expected_queries,
                     )
 
+    def _set_fd_stats(self, key, rel: Relation, fd: FD) -> statsmod.FDStats:
+        st = statsmod.fd_stats(rel, fd)
+        self.stats[key] = st
+        self._dirty_rows[key] = jnp.asarray(st.dirty_row)
+        return st
+
     def _refresh_stats(self, table: str) -> None:
         """Recompute one table's per-rule statistics after an append and
         fold the new instance size into the existing cost models in place
@@ -329,8 +414,7 @@ class Daisy:
             key = (table, rule.name)
             cm = self.cost.get(key)
             if isinstance(rule, FD):
-                st = statsmod.fd_stats(rel, rule)
-                self.stats[key] = st
+                st = self._set_fd_stats(key, rel, rule)
                 if cm is not None:
                     cm.n, cm.df = n, float(n)
                     cm.epsilon, cm.p = st.epsilon, st.p_est
@@ -462,11 +546,11 @@ class Daisy:
         the Fig. 11 dirty-group gate without ever being marked, so they are
         not background work either).  The single definition the ledger's
         per-strip counts are folded from (DESIGN.md §11)."""
-        rule = self._rule_named(table, rule_name)
+        self._rule_named(table, rule_name)  # KeyError for an unknown rule
         cold = unchecked(rel, rule_name)
-        st = self.stats.get((table, rule_name))
-        if isinstance(rule, FD) and st is not None:
-            cold = cold & jnp.asarray(st.dirty_row)
+        dirty = self._dirty_rows.get((table, rule_name))
+        if dirty is not None:
+            cold = cold & dirty
         return cold
 
     def cold_rows(self, table: str, rule_name: str) -> jnp.ndarray:
@@ -785,25 +869,26 @@ class Daisy:
             mark_scope = rel.valid
             rep.answer_size = int(to_host(jnp.sum(scope)))
         else:
-            answer = (
-                answer_override
+            ans = (
+                _Answer(answer_override)
                 if answer_override is not None
-                else filter_mask(rel, step.preds)
+                else self._answer(table, step.preds)
             )
-            rep.answer_size = int(to_host(jnp.sum(answer)))
+            answer = ans.mask
             # Fig. 11 skip: answer touches no dirty group and nothing unchecked
             if st is not None:
-                dirty_hit = bool(
-                    to_host(
-                        jnp.any(answer & jnp.asarray(st.dirty_row) & unchecked(rel, fd.name))
-                    )
+                gate = self._gate(
+                    ans, rel, fd.name, dirty=self._dirty_rows[(table, fd.name)]
                 )
-                if not dirty_hit:
+                rep.answer_size = ans.size
+                if not gate["hit"]:
                     rep.mode = "skipped"
                     report.steps.append(rep)
                     if cm and record_cost:
                         cm.record(rep.answer_size, 0, 0.0, 0)
                     return
+            else:
+                rep.answer_size = self._size(ans)
             with self.tracer.span(
                 "clean.relax", rule=fd.name, table=table
             ) as sp:
@@ -975,34 +1060,41 @@ class Daisy:
         scope_ledger = self.ledger.register(table, dc.name, rel.capacity)
         rep = StepReport(dc.name, table, step.mode)
 
-        answer = filter_mask(rel, step.preds) if step.preds else rel.valid
         mode = step.mode
-        if mode == "auto" and st is not None:
-            answer_size = int(to_host(jnp.sum(answer)))
-            pivot_vals = to_host(rel.columns[st.pivot])[to_host(answer)]
-            dec = statsmod.algorithm2_decide(
-                st,
-                pivot_vals,
-                answer_size,
-                scope_ledger.support,
-                self.config.accuracy_threshold,
-            )
-            rep.alg2_accuracy = dec.accuracy
-            rep.alg2_support = dec.support
-            mode = "full" if dec.full_clean else "incremental"
-        elif mode == "auto":
+        ans = self._answer(table, step.preds)
+        gate = None
+        if mode == "auto":
             mode = "incremental"
+            if st is not None:
+                # Algorithm 2 reads only the extremes of the answer's pivot
+                # values, which the gate returns with its size
+                gate = self._gate(ans, rel, dc.name, pivot=rel.columns[st.pivot])
+                dec = statsmod.algorithm2_decide(
+                    st,
+                    np.array([gate["lo"], gate["hi"]]),
+                    ans.size,
+                    scope_ledger.support,
+                    self.config.accuracy_threshold,
+                )
+                rep.alg2_accuracy = dec.accuracy
+                rep.alg2_support = dec.support
+                mode = "full" if dec.full_clean else "incremental"
 
         # resolve the scan scope: which rows of the comparison matrix this
         # step owns, and the covering kernel block range (the strip grid)
-        live = unchecked(rel, dc.name)
         cold_ids = scope_ledger.cold_strips()
         cold_frac = scope_ledger.cold_fraction
         row_blocks = None
         row_block_ids = None
         if mode == "incremental":
-            row_scope = answer & live
+            # the answer's unchecked rows, counted by the gate
+            if gate is None:
+                gate = self._gate(ans, rel, dc.name)
+            rep.mode = mode
+            rep.answer_size = ans.size
+            has_work = bool(gate["hit"])
         else:
+            live = unchecked(rel, dc.name)
             sel = cold_ids
             if step.strips is not None:
                 # drop strips that raced warm since the step was planned
@@ -1022,8 +1114,12 @@ class Daisy:
                 )
             else:
                 row_scope = jnp.zeros_like(rel.valid)
-        rep.mode = mode if mode != "strip" else rep.mode
-        rep.answer_size = int(to_host(jnp.sum(row_scope if mode == "strip" else answer)))
+            if mode == "strip":
+                rep.answer_size = int(to_host(jnp.sum(row_scope)))
+            else:
+                rep.mode = mode
+                rep.answer_size = self._size(ans)
+            has_work = bool(to_host(jnp.any(row_scope)))
 
         # idempotence gate (the DC analogue of the FD dirty-group skip): when
         # everything this step would scope is already checked for the rule,
@@ -1032,7 +1128,7 @@ class Daisy:
         # candidate support and advancing clean_version for no state change.
         # Repeated queries therefore skip, keeping answers version-stable
         # (the service cache's contract, DESIGN.md §9).
-        if not bool(to_host(jnp.any(row_scope))):
+        if not has_work:
             rep.mode = "skipped"
             report.steps.append(rep)
             if cm and record_cost:
@@ -1042,6 +1138,8 @@ class Daisy:
         mesh = self._detect_mesh(step)
         col_scope = rel.valid
         if mode == "incremental":
+            answer = ans.mask
+            row_scope = answer & unchecked(rel, dc.name)
             row_block_ids = self._active_blocks(row_scope)
         rel, det = self._dc_detect_repair(
             rel, dc, row_scope, col_scope, row_blocks, mesh, cm, rep,
@@ -1109,28 +1207,66 @@ class Daisy:
         with self._lock, self.tracer.span(
             "daisy.execute", table=query.table, joins=len(query.joins)
         ) as sp:
-            with self.tracer.span("execute.plan"):
-                plan = plan_query(
-                    query, self.rules, self._want_full(),
-                    lemma1_fast_path=self.config.lemma1_fast_path,
-                    ledger=self.ledger,
-                )
-            report = ExecReport(notes=list(plan.notes))
+            self._memo = memo = _AnswerMemo()
+            try:
+                with self.tracer.span("execute.plan"):
+                    plan = plan_query(
+                        query, self.rules, self._want_full(),
+                        lemma1_fast_path=self.config.lemma1_fast_path,
+                        ledger=self.ledger,
+                    )
+                report = ExecReport(notes=list(plan.notes))
 
-            if not query.joins:
-                result = self._execute_sp(query, plan, report)
-            else:
-                result = self._execute_join(query, plan, report)
-            sp.set(steps=len(report.steps), result_size=report.result_size)
+                if not query.joins:
+                    result = self._execute_sp(query, plan, report)
+                else:
+                    result = self._execute_join(query, plan, report)
+            finally:
+                self._memo = None
+            sp.set(
+                steps=len(report.steps), result_size=report.result_size,
+                filter_evals=memo.evals, filter_reuses=memo.reuses,
+            )
             return result
+
+    def _answer(self, table: str, preds) -> _Answer:
+        """The answer of ``preds`` over ``table``: inside ``execute``, the
+        last one computed while the relation object is the same (a cleaned
+        step or an ingest-delta replaces it), else filtered anew."""
+        rel = self.db[table]
+        memo = self._memo
+        key = tuple(preds)
+        if memo is not None and memo.rel is rel and memo.key == key:
+            memo.reuses += 1
+            _FILTERS.add(reused=True)
+            return memo.answer
+        ans = _Answer(filter_mask(rel, preds))
+        _FILTERS.add(reused=False)
+        if memo is not None:
+            memo.rel, memo.key, memo.answer = rel, key, ans
+            memo.evals += 1
+        return ans
+
+    def _gate(self, ans: _Answer, rel: Relation, rule_name: str, **extra):
+        """Run ``_step_gate`` for one step and read its scalars in one
+        ``to_host``; records the answer's size on ``ans``."""
+        out = to_host(_step_gate(ans.mask, rel.valid, rel.checked.get(rule_name), **extra))
+        ans.size = int(out["size"])
+        return out
+
+    def _size(self, ans: _Answer) -> int:
+        if ans.size is None:
+            ans.size = int(to_host(jnp.sum(ans.mask)))
+        return ans.size
 
     # ----------------------------------------------------------- SP queries
     def _execute_sp(self, query: Query, plan: PlanInfo, report: ExecReport) -> DaisyResult:
         self._run_steps(plan, report)
         rel = self.db[query.table]
         with self.tracer.span("execute.filter"):
-            mask = filter_mask(rel, query.preds)
-            report.result_size = int(to_host(jnp.sum(mask)))
+            ans = self._answer(query.table, query.preds)
+            mask = ans.mask
+            report.result_size = self._size(ans)
         result = DaisyResult(mask=mask, report=report)
         if query.groupby is not None:
             with self.tracer.span("execute.groupby"):

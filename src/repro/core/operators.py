@@ -26,6 +26,7 @@ arrays + overflow flag for joins (jnp.nonzero with static size).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -33,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.relation import CAND_VALUE, Relation
+from repro.core.relation import CAND_VALUE, Relation, possible_match
 from repro.core.setops import group_info, unique_counts
 
 
@@ -142,12 +143,36 @@ class JoinState:
 
 
 # ----------------------------------------------------------------- filters
-def filter_mask(rel: Relation, preds: Sequence[Pred]) -> jnp.ndarray:
-    """Possible-world conjunctive filter."""
-    mask = rel.valid
-    for p in preds:
-        mask = mask & rel.candidate_matches(p.col, p.op, p.value)
+@functools.partial(jax.jit, static_argnums=0)
+def _filter_program(structure, valid, arrays, values):
+    """The conjunctive possible-world filter as one program.  ``structure``
+    (static) holds ``(col, op, has_candidates)`` per predicate; ``arrays``
+    the column, plus candidates, kinds and counts where it has them; and
+    ``values`` the predicate constants, traced, so a new constant reuses
+    the program and a Python scalar stays weakly typed (it promotes as the
+    eager ``column op value`` does)."""
+    mask = valid
+    for (_, op, _), arrs, value in zip(structure, arrays, values):
+        mask = mask & possible_match(op, value, *arrs)
     return mask
+
+
+def filter_mask(rel: Relation, preds: Sequence[Pred]) -> jnp.ndarray:
+    """Possible-world conjunctive filter: a row qualifies iff every predicate
+    has a qualifying candidate (``Relation.candidate_matches``)."""
+    if not preds:
+        return rel.valid
+    structure, arrays = [], []
+    for p in preds:
+        has = p.col in rel.cand
+        structure.append((p.col, p.op, has))
+        arrays.append(
+            (rel.columns[p.col], rel.cand[p.col], rel.ckind[p.col], rel.ccount[p.col])
+            if has else (rel.columns[p.col],)
+        )
+    return _filter_program(
+        tuple(structure), rel.valid, tuple(arrays), tuple(p.value for p in preds)
+    )
 
 
 def key_candidates(rel: Relation, attr: str) -> Tuple[jnp.ndarray, jnp.ndarray]:

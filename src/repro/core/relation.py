@@ -166,19 +166,29 @@ class Relation:
         overlaps the predicate's satisfying set.
         """
         if name not in self.cand:
-            return _apply_op(self.columns[name], op, value)
-        cv = self.cand[name]
-        ck = self.ckind[name]
-        alive = self.ccount[name] > 0
-        val_ok = _apply_op(cv, op, value)
-        # Range candidate overlap rules against {EQ, NE, LT, LE, GT, GE} preds.
-        lt_ok = _range_lt_overlaps(cv, op, value)  # candidate == (-inf, cv)
-        gt_ok = _range_gt_overlaps(cv, op, value)  # candidate == (cv, +inf)
-        ok = jnp.where(ck == CAND_LT, lt_ok, jnp.where(ck == CAND_GT, gt_ok, val_ok))
-        any_ok = jnp.any(ok & alive, axis=1)
-        no_cand = ~jnp.any(alive, axis=1)
-        base_ok = _apply_op(self.columns[name], op, value)
-        return jnp.where(no_cand, base_ok, any_ok)
+            return possible_match(op, value, self.columns[name])
+        return possible_match(
+            op, value, self.columns[name],
+            self.cand[name], self.ckind[name], self.ccount[name],
+        )
+
+
+def possible_match(op: str, value, column, cand=None, ckind=None, ccount=None):
+    """``Relation.candidate_matches`` over the arrays it reads: the column
+    and, for an overlay attribute, its candidates, kinds and counts.  Plain
+    jnp, so it runs eagerly or inside a jitted program alike."""
+    if cand is None:
+        return _apply_op(column, op, value)
+    alive = ccount > 0
+    val_ok = _apply_op(cand, op, value)
+    # Range candidate overlap rules against {EQ, NE, LT, LE, GT, GE} preds.
+    lt_ok = _range_lt_overlaps(cand, op, value)  # candidate == (-inf, cand)
+    gt_ok = _range_gt_overlaps(cand, op, value)  # candidate == (cand, +inf)
+    ok = jnp.where(ckind == CAND_LT, lt_ok, jnp.where(ckind == CAND_GT, gt_ok, val_ok))
+    any_ok = jnp.any(ok & alive, axis=1)
+    no_cand = ~jnp.any(alive, axis=1)
+    base_ok = _apply_op(column, op, value)
+    return jnp.where(no_cand, base_ok, any_ok)
 
 
 def _apply_op(x: jnp.ndarray, op: str, value) -> jnp.ndarray:

@@ -139,6 +139,10 @@ def algorithm2_decide(
     """Algorithm 2 lines 3-10: given a query answer over the pivot attribute,
     estimate the accuracy of partial cleaning and decide full vs partial.
 
+    Only the extremes of ``answer_values`` are read: the executor passes
+    the min and max of the answer's pivot values, read on the device, and
+    never copies the answer's rows to the host.
+
     ``support`` is the fraction of the scope's comparison space already
     checked — since the work ledger (DESIGN.md §11), the caller passes its
     strip-coverage fraction directly (strips done / total), replacing the
