@@ -2,10 +2,26 @@
 
 A configuration is a JSON file of sizes and rules plus a module of the same
 name that generates its instance from a seed.  The module exposes
-``generate(cfg, seed) -> Instance``: the clean instance, the dirty one that
+``generate(cfg, rng) -> Instance``: the clean instance, the dirty one that
 is served, and which rows each rule's error injection edited.  Everything
 is host numpy; the benchmark never imports the program's own generators,
 which later changes to the program may alter.
+
+A configuration of one table keeps ``rows``, ``columns``, ``rules`` and
+``errors`` at its top level, and its table is named by ``table`` (default:
+the configuration's ``name``).  A configuration of several tables lists
+them under ``tables``, each entry with its own ``name``, ``rows``,
+``columns``, ``rules``, ``errors`` and ``reduced``; its module's
+``generate`` returns ``{table: Instance}``.  ``tables(cfg)`` gives each
+table's view: the configuration's shared keys (``daisy``, ``server``, ...)
+with the table's own on top.
+
+The module may also define ``check_answers`` (see ``bench/reference.py``):
+its own reference for answers the shared one cannot read, such as join
+lineage.
+
+``small`` holds the sizes the CPU tests run the configuration at, as
+overrides (see ``override``).
 """
 
 from __future__ import annotations
@@ -42,21 +58,53 @@ class Instance:
         return len(next(iter(self.dirty.values())))
 
 
-def load_config(name: str) -> dict:
-    """The configuration's JSON, with the generator module found by name."""
-    path = BENCH / "configs" / f"{name}.json"
-    cfg = json.loads(path.read_text())
+def load_config(name: str, root: Path = BENCH) -> dict:
+    """The configuration's JSON under ``root/configs``, with the generator
+    module found by name (and its ``check_answers``, where it has one)."""
+    cfg = json.loads((root / "configs" / f"{name}.json").read_text())
     spec = importlib.util.spec_from_file_location(
-        f"bench_config_{name.replace('-', '_')}", BENCH / "configs" / f"{name}.py"
+        f"bench_config_{name.replace('-', '_')}", root / "configs" / f"{name}.py"
     )
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     cfg["_generate"] = module.generate
+    if hasattr(module, "check_answers"):
+        cfg["_check_answers"] = module.check_answers
     return cfg
 
 
-def generate(cfg: dict, seed: int, stream: int = STREAM_DATA) -> Instance:
-    return cfg["_generate"](cfg, rng(seed, stream))
+def override(cfg: dict, overrides: dict) -> dict:
+    """``cfg`` with the keys of ``overrides`` replaced; under ``tables``,
+    ``overrides`` maps a table's name to the keys replaced in its entry."""
+    out = {**cfg, **{k: v for k, v in overrides.items() if k != "tables"}}
+    if "tables" in overrides:
+        per = overrides["tables"]
+        unknown = set(per) - {t["name"] for t in cfg["tables"]}
+        if unknown:
+            raise KeyError(f"{cfg['name']}: no tables {sorted(unknown)}")
+        out["tables"] = [{**t, **per.get(t["name"], {})} for t in cfg["tables"]]
+    return out
+
+
+def tables(cfg: dict) -> Dict[str, dict]:
+    """Each table's view of the configuration, in the configuration's order
+    (the first is the one a query names by default)."""
+    if "tables" not in cfg:
+        return {cfg.get("table", cfg["name"]): cfg}
+    shared = {k: v for k, v in cfg.items() if k != "tables"}
+    return {t["name"]: {**shared, **t} for t in cfg["tables"]}
+
+
+def generate(cfg: dict, seed: int, stream: int = STREAM_DATA) -> Dict[str, Instance]:
+    """Every table's instance, by table name."""
+    out = cfg["_generate"](cfg, rng(seed, stream))
+    if isinstance(out, Instance):
+        out = {cfg.get("table", cfg["name"]): out}
+    names = list(tables(cfg))
+    if set(out) != set(names):
+        raise ValueError(f"{cfg['name']}: generated tables {sorted(out)}, "
+                         f"configured {names}")
+    return {t: out[t] for t in names}
 
 
 def replace_values(

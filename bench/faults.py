@@ -11,8 +11,11 @@ Each is a context manager that patches the program underneath a run:
   (no candidate is merged).
 * ``half_batch`` — the DC kernel scans only half of each worklist's
   partner blocks.
-* ``answer_altered`` — each executed answer loses its first row where it
-  is produced.
+* ``answer_altered`` — each executed answer is altered where it is
+  produced: a one-table answer loses its first qualifying row; a join
+  answer's first valid pair of lineage takes the next row of the last
+  joined table in place of its own (a dropped pair would go unseen where
+  the pair is one the semantics allow but do not require).
 
 The cells run on one chip, so the fault of a left-out exchange between
 chips does not arise.
@@ -21,6 +24,7 @@ chips does not arise.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 
 import numpy as np
 
@@ -72,18 +76,32 @@ def half_batch():
     return _patch(kops, "dc_pair_scan", half)
 
 
+def _first(flags) -> int:
+    return int(np.argmax(np.asarray(flags)))
+
+
+@contextlib.contextmanager
 def answer_altered():
     from repro.core.executor import Daisy
 
-    execute_sp = Daisy._execute_sp
+    execute_sp, execute_join = Daisy._execute_sp, Daisy._execute_join
 
-    def altered(self, query, plan, report):
+    def altered_sp(self, query, plan, report):
         result = execute_sp(self, query, plan, report)
-        first = int(np.argmax(np.asarray(result.mask)))
-        result.mask = result.mask.at[first].set(False)
+        result.mask = result.mask.at[_first(result.mask)].set(False)
         return result
 
-    return _patch(Daisy, "_execute_sp", altered)
+    def altered_join(self, query, plan, report):
+        result = execute_join(self, query, plan, report)
+        join = result.join
+        last = join.tables[-1]
+        rows = {**join.rows, last: join.rows[last].at[_first(join.valid)].add(1)}
+        result.join = dataclasses.replace(join, rows=rows)
+        return result
+
+    with _patch(Daisy, "_execute_sp", altered_sp), \
+            _patch(Daisy, "_execute_join", altered_join):
+        yield
 
 
 FAULTS = {

@@ -3,7 +3,8 @@ worklists launched in the window (``bench/work.py``, from the launch
 geometry the ``clean.detect`` spans record) at the chip's peak HBM
 bandwidth, over the kernel's device time.  HBM bound only: the chip
 publishes no rate for the vector compares the kernel spends its time on,
-so no compute bound is claimed."""
+so no compute bound is claimed.  Each span's rows, rules and data are its
+table's (the span's ``table`` attribute)."""
 
 import math
 
@@ -13,16 +14,16 @@ from work import dc_widths, launch_bytes
 def read(ctx):
     if ctx.trace is None or ctx.trace.kernel_events == 0:
         return None
-    dcs = {r["name"]: r["dc"] for r in ctx.cfg["rules"] if "dc" in r}
-    block = int(ctx.cfg["daisy"].get("dc_block", 256))
-    nb = math.ceil(ctx.cfg["rows"] / block)
-    widths = {name: dc_widths(atoms, ctx.data) for name, atoms in dcs.items()}
+    tables = {}
     total = 0
     for s in ctx.spans:
         a = s.attrs
-        if s.name != "clean.detect" or a.get("rule") not in dcs:
+        if s.name != "clean.detect" or not a.get("tiles_launched"):
             continue
-        if not a.get("tiles_launched"):
+        if a["table"] not in tables:
+            tables[a["table"]] = _table(ctx.tables[a["table"]])
+        dcs, widths, block, nb = tables[a["table"]]
+        if a.get("rule") not in dcs:
             continue
         nrows = _side(a.get("row_block_ids"), a.get("row_blocks"), nb)
         ncols = _side(a.get("col_block_ids"), a.get("col_blocks"), nb)
@@ -34,6 +35,14 @@ def read(ctx):
         return None
     least_s = total / ctx.peaks["hbm_bytes_per_s"]
     return 100.0 * least_s / ctx.trace.kernel_s
+
+
+def _table(view):
+    """The table's DCs, their columns' exact widths, block and blocks."""
+    dcs = {r["name"]: r["dc"] for r in view.cfg["rules"] if "dc" in r}
+    block = int(view.cfg["daisy"].get("dc_block", 256))
+    widths = {name: dc_widths(atoms, view.data) for name, atoms in dcs.items()}
+    return dcs, widths, block, math.ceil(view.cfg["rows"] / block)
 
 
 def _side(ids, blocks, nb):

@@ -25,8 +25,18 @@ configuration file.  It judges two things the window produced.
   evidence only: a range no tighter than the full one, and only where the
   row violates the DC at all.  This covers relaxation (an FD's evidence is
   its whole group), detection (the DC kernel's counts and extremal
-  partners), repair and the merge.  A seeded sample of rows is compared;
-  limit 0.
+  partners), repair and the merge.  A seeded sample of rows of each table
+  is compared, each table against its own rules; limit 0.
+
+Answers of one table are judged here.  Answers this reference cannot read,
+such as a join's lineage, go to the configuration's own
+``check_answers(tables, sems, answers)`` (``tables``: each table's view of
+the configuration; ``sems``: each table's ``Semantics``; ``answers``: the
+``(traffic.QuerySpec, result)`` of each sampled join answer), which returns
+its own numbers, each ``{"value", "limit"}``, for ``compared``.  It is
+called whenever the configuration defines it.  A join answer in a run
+whose configuration defines none stops the run (``UncheckedAnswers``): it
+is never passed unchecked.
 """
 
 from __future__ import annotations
@@ -382,8 +392,12 @@ class Semantics:
         return True
 
 
-def sample_rows(sem: Semantics, checked: Dict[str, np.ndarray], seed: int):
-    r = datagen.rng(seed, datagen.STREAM_SAMPLE)
+class UncheckedAnswers(RuntimeError):
+    """Join answers were served and the configuration has no reference for
+    them."""
+
+
+def sample_rows(sem: Semantics, checked: Dict[str, np.ndarray], r: np.random.Generator):
     out = {}
     for model in sem.fds + sem.dcs:
         ch = checked[model.name]
@@ -397,9 +411,11 @@ def sample_rows(sem: Semantics, checked: Dict[str, np.ndarray], seed: int):
     return out
 
 
-def check(cfg: dict, inst, dep, records, seed: int) -> Dict[str, dict]:
-    """The numbers ``correct`` compares, each with its limit."""
-    sem = Semantics(cfg, inst.dirty)
+def check(cfg: dict, insts, dep, records, seed: int) -> Dict[str, dict]:
+    """The numbers ``correct`` compares, each with its limit.  ``insts``:
+    each table's generated instance."""
+    views = datagen.tables(cfg)
+    sems = {t: Semantics(views[t], insts[t].dirty) for t in views}
     results = {}
     for rec in records:
         if rec.t1 is not None and rec.ticket.error is None:
@@ -409,25 +425,45 @@ def check(cfg: dict, inst, dep, records, seed: int) -> Dict[str, dict]:
         r = datagen.rng(seed, datagen.STREAM_ANSWERS)
         chosen = [chosen[i] for i in sorted(r.choice(len(chosen), MAX_ANSWERS, replace=False))]
     answer_wrong = 0
-    for spec, result in chosen:
+    joined = []
+    for q, result in chosen:
+        if q.joins:
+            joined.append((q, result))
+            continue
         mask = np.asarray(result.mask)
         groups = None
         if result.groups is not None:
             groups = {k: np.asarray(v) for k, v in result.groups.items()}
-        answer_wrong += sem.check_answer(spec, mask, groups)
+        answer_wrong += sems[q.table].check_answer(q, mask, groups)
+    hook = cfg.get("_check_answers")
+    if joined and hook is None:
+        raise UncheckedAnswers(
+            f"configuration {cfg['name']!r} served {len(joined)} join answers and "
+            "defines no check_answers in its module: a join answer is never "
+            "passed unchecked"
+        )
+    own = {} if hook is None else hook(views, sems, joined)
 
-    rel = dep.daisy.db[dep.table]
-    n = inst.rows
-    checked = {name: np.asarray(c)[:n] for name, c in rel.checked.items()}
-    rows = sample_rows(sem, checked, seed)
+    r = datagen.rng(seed, datagen.STREAM_SAMPLE)
+    state_wrong = 0
+    for t, sem in sems.items():
+        rel = dep.daisy.db[t]
+        n = insts[t].rows
+        checked = {name: np.asarray(c)[:n] for name, c in rel.checked.items()}
+        rows = sample_rows(sem, checked, r)
 
-    def overlay(attr, idx):
-        idx = np.asarray(idx, np.int32)
-        return (np.asarray(rel.cand[attr][idx]), np.asarray(rel.ccount[attr][idx]),
-                np.asarray(rel.ckind[attr][idx]))
+        def overlay(attr, idx):
+            idx = np.asarray(idx, np.int32)
+            return (np.asarray(rel.cand[attr][idx]), np.asarray(rel.ccount[attr][idx]),
+                    np.asarray(rel.ckind[attr][idx]))
 
-    state_wrong = sem.check_state(checked, overlay, rows)
-    return {
+        state_wrong += sem.check_state(checked, overlay, rows)
+    compared = {
         "answer_rows_wrong": {"value": answer_wrong, "limit": 0},
         "state_rows_wrong": {"value": state_wrong, "limit": 0},
     }
+    clash = set(own) & set(compared)
+    if clash:
+        raise ValueError(f"{cfg['name']}: check_answers reuses the names {sorted(clash)}")
+    compared.update(own)
+    return compared

@@ -23,6 +23,17 @@ its own readers (``bench/layer_metrics``) and decides ``correct`` with its
 own numpy reference (``bench/reference.py``).  Without a TPU, or with
 fewer chips than the cell asks for, it exits non-zero and prints no
 result.
+
+A cell is found by its names alone: ``bench/configs/<config>.json`` and
+``<config>.py``, ``bench/traffic/<traffic>.json`` and
+``bench/layer_metrics/<metric>.py``.  A configuration may hold several
+tables (``tables``, see ``bench/datagen.py``): every table is served by
+one ``Daisy``, each with its own candidate overlays and rules, warm-up
+waits until every rule of every table is clean, and the correctness check
+samples each table's state.  Its traffic may join them (``table``,
+``joins`` and ``"table.column"`` names, see ``bench/traffic.py``); join
+answers are judged by the configuration's own ``check_answers``, and a run
+whose configuration has none stops rather than pass them unchecked.
 """
 
 from __future__ import annotations
@@ -195,9 +206,9 @@ def overlay_attrs(cfg):
 
 
 class Deployment:
-    """One served instance: relation, executor, server, serving thread and
-    background cleaner (not yet started), as the configuration states
-    them."""
+    """One served instance: one relation per table, executor, server,
+    serving thread and background cleaner (not yet started), as the
+    configuration states them."""
 
     def __init__(self, cfg, data, tracer=None):
         from repro.core.executor import Daisy, DaisyConfig
@@ -205,15 +216,20 @@ class Deployment:
         from repro.service import BackgroundCleaner, QueryServer
         from repro.service.cache import ResultCache
 
-        self.table = cfg.get("table", cfg["name"])
+        self.views = datagen.tables(cfg)
+        self.tables = tuple(self.views)
+        self.table = self.tables[0]  # the one a query names by default
         daisy_cfg = DaisyConfig(**cfg["daisy"])
-        rel = make_relation(
-            data, overlay=overlay_attrs(cfg), k=daisy_cfg.k,
-            rules=[r["name"] for r in cfg["rules"]],
-        )
+        rels = {
+            t: make_relation(
+                data[t], overlay=overlay_attrs(view), k=daisy_cfg.k,
+                rules=[r["name"] for r in view["rules"]],
+            )
+            for t, view in self.views.items()
+        }
         self.daisy = Daisy(
-            {self.table: rel}, {self.table: program_rules(cfg)}, daisy_cfg,
-            tracer=tracer,
+            rels, {t: program_rules(view) for t, view in self.views.items()},
+            daisy_cfg, tracer=tracer,
         )
         srv = cfg["server"]
         self.server = QueryServer(
@@ -228,17 +244,12 @@ class Deployment:
             self.daisy, server=self.server, **cfg["background"]
         )
 
-    def query(self, spec: traffic.QuerySpec):
-        from repro.core.operators import GroupBySpec, Pred, Query
+    def scopes(self):
+        """Every (table, rule) the background cleaner and warm-up clean."""
+        return [(t, r["name"]) for t, view in self.views.items() for r in view["rules"]]
 
-        groupby = None
-        if spec.groupby is not None:
-            keys, agg, value = spec.groupby
-            groupby = GroupBySpec(keys=keys, agg=agg, value=value)
-        return Query(
-            self.table, preds=tuple(Pred(c, op, v) for c, op, v in spec.preds),
-            project=spec.project, groupby=groupby,
-        )
+    def relations(self):
+        return [self.daisy.db[t] for t in self.tables]
 
     def stop(self) -> None:
         self.server.stop()
@@ -248,26 +259,46 @@ class Deployment:
             raise RuntimeError("serving thread did not stop")
 
 
+def build_query(q: traffic.QuerySpec):
+    """The program's ``Query`` for a drawn query."""
+    from repro.core.operators import GroupBySpec, JoinClause, Query
+
+    groupby = None
+    if q.groupby is not None:
+        keys, agg, value = q.groupby
+        groupby = GroupBySpec(keys=keys, agg=agg, value=value, table=q.groupby_table)
+    joins = tuple(JoinClause(j.right, j.left_on, j.right_on, _preds(j.preds))
+                  for j in q.joins) if q.joins else ()
+    return Query(q.table, preds=_preds(q.preds), project=q.project, joins=joins,
+                 groupby=groupby)
+
+
+def _preds(preds):
+    from repro.core.operators import Pred
+
+    return tuple(Pred(c, op, v) for c, op, v in preds)
+
+
 def warm_up(cfg, mix, seed: int, data, clock: CompileClock) -> int:
-    """Compile, or load from the persistent cache, what the window will run;
-    returns the passes it took.
+    """Compile, or load from the persistent cache, what the window will run
+    (``data``: each table's dirty columns); returns the passes it took.
 
     The program compiles a kernel for every DC worklist length it meets, and
     those lengths follow from the data, the traffic and how the sessions and
     the background cleaner interleave.  So a pass does what the window does,
     on a scratch copy of this run's instance: the sessions' streams of this
     seed, closed loop, with the background cleaner on, until every template
-    has been answered and every rule's scope is clean; then the copy is
-    thrown away.  Passes repeat while the last one still wrote a program
-    to the persistent cache (met a shape no earlier run had), up to
-    ``WARM_UP_PASSES``.  The window starts from a fresh copy."""
+    has been answered and every rule's scope on every table is clean; then
+    the copy is thrown away.  Passes repeat while the last one still wrote
+    a program to the persistent cache (met a shape no earlier run had), up
+    to ``WARM_UP_PASSES``.  The window starts from a fresh copy."""
     names = {t.name for t in mix.templates}
     for n in range(1, WARM_UP_PASSES + 1):
         written = clock.writes
         dep = Deployment(cfg, data)
-        rules = [r["name"] for r in cfg["rules"]]
-        for rule in rules:
-            dep.daisy.cold_count(dep.table, rule)  # sized before the threads read it
+        scopes = dep.scopes()
+        for table, rule in scopes:
+            dep.daisy.cold_count(table, rule)  # sized before the threads read it
         stop = threading.Event()
         t0 = time.perf_counter()
         try:
@@ -277,7 +308,7 @@ def warm_up(cfg, mix, seed: int, data, clock: CompileClock) -> int:
                 with records.lock:
                     seen = {r.spec.template for r in records if r.t1 is not None}
                 if seen == names and not any(
-                    dep.daisy.cold_count(dep.table, rule) for rule in rules
+                    dep.daisy.cold_count(table, rule) for table, rule in scopes
                 ):
                     break
             stop.set()
@@ -324,7 +355,7 @@ def serve_window(dep, mix, seed: int, t_start: float, t_end: float,
         sess = dep.server.open_session(f"analyst{i}")
         while True:
             query_spec = next(stream)
-            q = dep.query(query_spec)
+            q = build_query(query_spec)
             t0 = time.perf_counter()
             if t0 >= t_end or stop.is_set():
                 break
@@ -368,29 +399,31 @@ def applies(metric: dict, cell: str, e2e_names) -> bool:
 
 # -------------------------------------------------------------------- run
 def run_cell(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
-             devices=None, peaks=None, overrides=None) -> dict:
+             devices=None, peaks=None, overrides=None, root: Path = BENCH) -> dict:
     """One run of one cell; returns the result object (without printing).
 
-    ``overrides`` replaces configuration keys (the tests run small
-    instances on the CPU with it)."""
+    ``overrides`` replaces configuration keys (``datagen.override``; the
+    tests run small instances on the CPU with it), and ``root`` is the
+    directory that holds ``configs`` and ``traffic``."""
     import jax
 
     from repro.obs import Tracer
     from repro.obs.trace import NULL_TRACER
 
     devices = devices or jax.devices()[:cell["chips"]]
-    cfg = datagen.load_config(cell["config"])
-    cfg.update(overrides or {})
-    mix = traffic.Mix(traffic.load_mix(cell["traffic"]), cfg.get("domains", {}))
+    cfg = datagen.override(datagen.load_config(cell["config"], root), overrides or {})
+    mix = traffic.Mix(traffic.load_mix(cell["traffic"], root), cfg.get("domains", {}),
+                      next(iter(datagen.tables(cfg))))
     clock = CompileClock()
 
-    inst = datagen.generate(cfg, seed)
+    insts = datagen.generate(cfg, seed)
+    data = {t: inst.dirty for t, inst in insts.items()}
     cache_writes(True)
-    passes = warm_up(cfg, mix, seed, inst.dirty, clock)
+    passes = warm_up(cfg, mix, seed, data, clock)
     cache_writes(False)
     tracer = Tracer(capacity=1 << 21) if trace else NULL_TRACER
-    dep = Deployment(cfg, inst.dirty, tracer=tracer)
-    jax.block_until_ready(dep.daisy.db[dep.table].valid)
+    dep = Deployment(cfg, data, tracer=tracer)
+    jax.block_until_ready([rel.valid for rel in dep.relations()])
     setup_s = time.perf_counter() - PROCESS_START
 
     trace_dir = RUNS_DIR / f"trace-{cell['name']}"
@@ -419,7 +452,7 @@ def run_cell(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
     if trace:
         # stop only once the device has drained, so that an operation
         # running across the window's end is in the trace, clipped to it
-        jax.block_until_ready(dep.daisy.db[dep.table])
+        jax.block_until_ready(dep.relations())
         jax.profiler.stop_trace()
     stats = [d.memory_stats() or {} for d in devices]
     peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
@@ -458,9 +491,13 @@ def run_cell(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
         device["busy_s"] = red.busy_s
         device["window_s"] = red.window_s
         breakdown = red.breakdown
+        # ``data``: the columns of a one-table configuration (None where it
+        # has several); ``tables``: each table's view and columns
         ctx = SimpleNamespace(
-            cfg=cfg, data=inst.dirty, answers=in_window, spans=spans,
-            compiles=compiles, trace=red, counters=(m0, m1), peaks=peaks,
+            cfg=cfg, data=data[dep.table] if len(data) == 1 else None,
+            tables={t: SimpleNamespace(cfg=dep.views[t], data=data[t]) for t in data},
+            answers=in_window, spans=spans, compiles=compiles, trace=red,
+            counters=(m0, m1), peaks=peaks,
         )
         names = {m["name"] for m in e2e}
         for m in bench["per_layer"]:
@@ -470,7 +507,7 @@ def run_cell(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
-    compared = reference.check(cfg, inst, dep, records, seed)
+    compared = reference.check(cfg, insts, dep, records, seed)
     del dep
     correct = failed == 0 and all(c["value"] <= c["limit"] for c in compared.values())
     in_window = clock.between(t_start, t_end)

@@ -13,7 +13,19 @@ and a list of query templates with their shares.  A template holds
   otherwise each slot draws by its own ``draw`` (``{"zipf": s}`` or
   ``{"uniform": true}``);
 * ``groupby`` (``keys``, ``agg``, ``value``) and ``project``, as the query
-  returns them.
+  returns them;
+* ``table``: the table the query reads (default: the configuration's only
+  or first table), and ``joins``: ``{"right", "left_on", "right_on"}`` per
+  joined table, in join order.
+
+A column of a joined table is written ``"table.column"``: a predicate so
+written becomes that join's ``right_preds``.  The keys and the value of
+``groupby`` may be qualified the same way; when they all name one table
+the query's group-by names it (``GroupBySpec.table``), and when they span
+tables they are passed as written, for the program to resolve.  A name
+without a known table before its dot is a column of the query's table.
+Names are resolved once, when the mix is read, so a drawn ``QuerySpec``
+already says which table each name belongs to.
 
 Every session draws from its own stream of the run's seed, so a seed fixes
 each session's sequence of queries whatever the timing of the run.  Ranks
@@ -47,18 +59,34 @@ BENCH = Path(__file__).resolve().parent
 STRATA = 64
 
 
+Pred = Tuple[str, str, object]
+
+
+@dataclasses.dataclass(frozen=True)
+class Join:
+    right: str
+    left_on: str
+    right_on: str
+    preds: Tuple[Pred, ...] = ()  # on the right table, unqualified
+
+
 @dataclasses.dataclass(frozen=True)
 class QuerySpec:
-    """One generated query, in the benchmark's own terms."""
+    """One generated query, in the benchmark's own terms, with every name
+    given its table (see the module docstring): what the program is asked
+    (``run.build_query``) and what the reference judges."""
 
     template: str
-    preds: Tuple[Tuple[str, str, object], ...]
+    preds: Tuple[Pred, ...]  # on ``table``
     groupby: Optional[Tuple[Tuple[str, ...], str, Optional[str]]] = None
     project: Tuple[str, ...] = ()
+    table: Optional[str] = None  # the mix sets it: the template's or the default
+    joins: Tuple[Join, ...] = ()
+    groupby_table: Optional[str] = None  # where every group-by name is on one
 
 
-def load_mix(name: str) -> dict:
-    return json.loads((BENCH / "traffic" / f"{name}.json").read_text())
+def load_mix(name: str, root: Path = BENCH) -> dict:
+    return json.loads((root / "traffic" / f"{name}.json").read_text())
 
 
 def _slot_choices(slot: dict, domains: Dict[str, int]) -> List[List[list]]:
@@ -98,10 +126,34 @@ def _weights(draw: dict, n: int) -> np.ndarray:
 
 
 class _Template:
-    def __init__(self, spec: dict, domains: Dict[str, int]):
+    """One template with its names resolved once, against its own tables
+    (``default_table`` where it names none): a draw only picks choices."""
+
+    def __init__(self, spec: dict, domains: Dict[str, int], default_table: str):
         self.name = spec["name"]
         self.share = float(spec["share"])
-        self.choices = [_slot_choices(s, domains) for s in spec["slots"]]
+        self.table = spec.get("table") or default_table
+        self.joins = tuple(
+            (j["right"], j["left_on"], j["right_on"]) for j in spec.get("joins", ())
+        )
+        known = {self.table, *(r for r, _, _ in self.joins)}
+        if len(known) != 1 + len(self.joins):
+            raise ValueError(f"{self.name}: a table is joined twice")
+
+        def split(name: str):
+            """(table, column) of a name; table None where it names none."""
+            head, dot, col = name.partition(".")
+            return (head, col) if dot and head in known else (None, name)
+
+        def pred(col, op, value):
+            table, col = split(col)
+            return table or self.table, (col, op, value)
+
+        # each choice as (table, predicate) pairs
+        self.choices = [
+            [tuple(pred(*p) for p in choice) for choice in _slot_choices(s, domains)]
+            for s in spec["slots"]
+        ]
         sizes = [len(c) for c in self.choices]
         joint = spec.get("draw", {}).get("joint")
         self.sizes = sizes
@@ -111,10 +163,17 @@ class _Template:
             for s, n in zip(spec["slots"], sizes)
         ]
         gb = spec.get("groupby")
-        self.groupby = (
-            None if gb is None
-            else (tuple(gb["keys"]), gb.get("agg", "count"), gb.get("value"))
-        )
+        self.groupby, self.groupby_table = None, None
+        if gb is not None:
+            keys, value = tuple(gb["keys"]), gb.get("value")
+            self.groupby = (keys, gb.get("agg", "count"), value)
+            names = [split(n) for n in keys + ((value,) if value else ())]
+            named = {t or self.table for t, _ in names}
+            if any(t for t, _ in names) and len(named) == 1:
+                self.groupby_table = named.pop()
+                cols = [c for _, c in names]
+                self.groupby = (tuple(cols[:len(keys)]), self.groupby[1],
+                                cols[len(keys)] if value else None)
         self.project = tuple(spec.get("project", ()))
 
     def drawers(self, r: np.random.Generator):
@@ -128,20 +187,28 @@ class _Template:
             idx = np.unravel_index(next(drawers[0]), self.sizes)
         else:
             idx = [next(d) for d in drawers]
-        preds = []
+        preds = {self.table: [], **{r: [] for r, _, _ in self.joins}}
         for choices, i in zip(self.choices, idx):
-            preds.extend(tuple(p) for p in choices[int(i)])
-        return QuerySpec(self.name, tuple(preds), self.groupby, self.project)
+            for table, pred in choices[int(i)]:
+                preds[table].append(pred)
+        return QuerySpec(
+            self.name, tuple(preds[self.table]), self.groupby, self.project,
+            self.table,
+            tuple(Join(r, lo, ro, tuple(preds[r])) for r, lo, ro in self.joins),
+            self.groupby_table,
+        )
 
 
 class Mix:
-    """A traffic mix bound to a configuration's domains."""
+    """A traffic mix bound to a configuration's domains and the table a
+    template reads where it names none."""
 
-    def __init__(self, spec: dict, domains: Dict[str, int]):
+    def __init__(self, spec: dict, domains: Dict[str, int], default_table: str):
         if spec.get("loop") != "closed":
             raise ValueError("only closed-loop mixes are generated")
         self.sessions = int(spec["sessions"])
-        self.templates = [_Template(t, domains) for t in spec["templates"]]
+        self.templates = [_Template(t, domains, default_table)
+                          for t in spec["templates"]]
         shares = np.array([t.share for t in self.templates])
         self.shares = shares / shares.sum()
 
